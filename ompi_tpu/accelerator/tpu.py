@@ -20,23 +20,38 @@ from ompi_tpu.accelerator.base import (
     accelerator_framework,
 )
 from ompi_tpu.core.errors import MPIError, ERR_ARG
-from ompi_tpu.mca.component import Component
+from ompi_tpu.mca.component import Component, ComponentFatal
 from ompi_tpu.mca.var import register_var, get_var
 
-# Published HBM bandwidth per chip generation, GB/s (How to Scale Your
-# Model, table of chip specs; reference analog: get_mem_bw via NVML).
-_HBM_BW_GBS = {
-    "TPU v2": 700.0,
-    "TPU v3": 900.0,
-    "TPU v4": 1228.0,
-    "TPU v5 lite": 819.0,
-    "TPU v5e": 819.0,
-    "TPU v5": 2765.0,
-    "TPU v5p": 2765.0,
-    "TPU v6 lite": 1640.0,
-    "TPU v6e": 1640.0,
-    "cpu": 50.0,
+# Published peaks per chip, keyed by the device_kind JAX reports:
+# (dense bf16 FLOP/s, HBM GB/s). Sources: Google Cloud TPU system
+# architecture pages per generation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s) and How to Scale Your Model's chip table. A TPU kind that
+# is not here is an error, not a default (reference analog: get_mem_bw
+# via NVML).
+PEAKS = {
+    "TPU v2": (45e12, 700.0),
+    "TPU v3": (123e12, 900.0),
+    "TPU v4": (275e12, 1228.0),
+    "TPU v5 lite": (197e12, 819.0),  # v5e
+    "TPU v5": (459e12, 2765.0),      # v5p
+    "TPU v6 lite": (918e12, 1640.0),  # v6e
 }
+# the CPU backend has no published peak; a nominal host bandwidth keeps
+# the CPU mesh's bandwidth-model users running
+_CPU_MEM_BW_GBS = 50.0
+
+
+def peaks(device) -> tuple:
+    """(bf16 FLOP/s, HBM GB/s) of a TPU device; raises for a TPU kind
+    missing from the table."""
+    kind = device.device_kind
+    if kind not in PEAKS:
+        raise MPIError(ERR_ARG, f"no published peaks for device_kind "
+                                f"{kind!r}: add it to accelerator/tpu.py "
+                                "PEAKS with its source")
+    return PEAKS[kind]
+
 
 register_var("accelerator", "tpu_mem_bw", 0.0, float,
              help="Override the HBM bandwidth estimate (GB/s); 0=auto",
@@ -78,11 +93,10 @@ class JaxAccelerator(AcceleratorModule):
         override = get_var("accelerator", "tpu_mem_bw")
         if override:
             return float(override)
-        kind = getattr(self._devices[device], "device_kind", "cpu")
-        for key, bw in _HBM_BW_GBS.items():
-            if kind.lower().startswith(key.lower()):
-                return bw
-        return _HBM_BW_GBS["cpu"]
+        dev = self._devices[device]
+        if dev.platform == "cpu":
+            return _CPU_MEM_BW_GBS
+        return peaks(dev)[1]
 
     # --- alloc / copy --------------------------------------------------
     def mem_alloc(self, nbytes: int, device: int = 0) -> Any:
@@ -110,8 +124,11 @@ class JaxAccelerator(AcceleratorModule):
     def synchronize(self, obj: Any = None) -> None:
         if obj is not None:
             obj.block_until_ready()
-        else:
-            (self._jax.device_put(0) + 0).block_until_ready()
+            return
+        # a device runs its queue in order: a trivial op behind it on
+        # every device drains them all
+        for dev in self._devices:
+            (self._jax.device_put(0, dev) + 0).block_until_ready()
 
     # --- IPC -----------------------------------------------------------
     # Wire format: u8 dtype-name length | dtype name | u8 ndim |
@@ -148,10 +165,17 @@ class TpuComponent(Component):
     PRIORITY = 50
 
     def query(self, **ctx: Any) -> Optional[AcceleratorModule]:
+        # only a missing jax selects null; a device that fails to come
+        # up raises rather than hide behind the host-only stub
+        try:
+            import jax  # noqa: F401
+        except ImportError:
+            return None
         try:
             return JaxAccelerator()
-        except Exception:
-            return None
+        except Exception as e:
+            raise ComponentFatal(f"jax devices failed to come up: {e}") \
+                from e
 
 
 class NullAccelerator(AcceleratorModule):
@@ -176,7 +200,7 @@ class NullAccelerator(AcceleratorModule):
         return False
 
     def get_mem_bw(self, device: int = 0) -> float:
-        return _HBM_BW_GBS["cpu"]
+        return _CPU_MEM_BW_GBS
 
     def mem_alloc(self, nbytes: int, device: int = 0) -> Any:
         return np.zeros(nbytes, dtype=np.uint8)

@@ -1,15 +1,16 @@
 """Frozen collective dispatch plans — the verb-layer dispatch-tax killer.
 
-BENCH_r05's ``dispatch_tax.verb_sweep`` put the per-verb layer overhead
-at 20-50us on top of a ~1.8us stub prologue: every ``ProcComm._coll``
-re-did the slot lookup and re-tested the metrics/sanitizer/trace live
-Vars, and every enabled instrumentation layer re-built its wrapper per
-call. A :class:`CollPlan` freezes all of that at FIRST dispatch: the
-resolved module fn, the sanitizer/trace interposition wrappers, and the
-metrics entry-stamp binding are composed once into ``plan.fn``, so the
-steady state in ``ProcComm._coll`` is one dict hit + an epoch compare +
-execute (reference analog: comm->c_coll is resolved once at selection;
-this extends the idea through the instrumentation stack).
+A pre-PR-1 bench record (not measured on the chip) put the per-verb
+layer overhead at 20-50us on top of a ~1.8us stub prologue: every
+``ProcComm._coll`` re-did the slot lookup and re-tested the
+metrics/sanitizer/trace live Vars, and every enabled instrumentation
+layer re-built its wrapper per call. A :class:`CollPlan` freezes all
+of that at FIRST dispatch: the resolved module fn, the sanitizer/trace
+interposition wrappers, and the metrics entry-stamp binding are
+composed once into ``plan.fn``, so the steady state in
+``ProcComm._coll`` is one dict hit + an epoch compare + execute
+(reference analog: comm->c_coll is resolved once at selection; this
+extends the idea through the instrumentation stack).
 
 Correctness of the freeze rests on invalidation — a stale plan would
 silently drop instrumentation a user just enabled (or keep paying for

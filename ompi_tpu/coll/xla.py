@@ -51,7 +51,6 @@ from ompi_tpu.mca.var import register_pvar
 from ompi_tpu.runtime import trace as _trace
 
 
-from ompi_tpu.parallel.axes import shard_map_compat as _shard_map
 
 
 class _CacheStats:
@@ -173,7 +172,7 @@ class XlaColl(CollModule):
     def _dispatch(self, comm, key, builder, *args):
         """Resolve (or build) the executable and run it under the
         coll.xla.dispatch span — the component-dispatch hook the
-        BENCH_r05 'where does the layer time go' question needs."""
+        'where does the layer time go' question needs."""
         fn = self._cached(comm, key, builder)
         if _trace.enabled():
             with _trace.span("coll.xla.dispatch", cat="coll",
@@ -186,7 +185,9 @@ class XlaColl(CollModule):
         from jax.sharding import PartitionSpec as P
 
         specs = tuple([P(comm.axis)] * n_in + ([P()] if rooted else []))
-        f = _shard_map(body, comm.mesh, specs, P(comm.axis))
+        f = jax.shard_map(body, mesh=comm.mesh,
+                          in_specs=specs,
+                          out_specs=P(comm.axis))
         return jax.jit(f)
 
     @staticmethod

@@ -448,7 +448,7 @@ class ProcComm(Intracomm):
         # Frozen-plan dispatch (coll/hier/plan.py): the SPC record,
         # metrics entry stamp, sanitizer interposition, and trace span
         # are pre-bound into plan.fn at first dispatch, so the steady
-        # state is ONE dict hit + an epoch compare (BENCH_r05's 20-50us
+        # state is ONE dict hit + an epoch compare (the 20-50us
         # per-verb layer tax re-did all of it per call). Stale-config
         # hazards are handled by invalidation: cvar watchers bump the
         # global epoch, Free clears the comm's plans, and revocation is

@@ -22,6 +22,12 @@ from ompi_tpu.mca.var import register_var, get_var
 from ompi_tpu.utils.output import get_logger
 
 
+class ComponentFatal(Exception):
+    """Raised by a component's query when its resource is present but
+    broken (a device that fails to come up): selection stops instead of
+    falling back to a lower-priority component."""
+
+
 class Component:
     """Base class for all MCA components.
 
@@ -102,6 +108,8 @@ class Framework:
         for comp in self._candidates():
             try:
                 module = comp.query(**ctx)
+            except ComponentFatal:
+                raise
             except Exception as e:  # a broken component must not kill init
                 self.log.warning("component %s query failed: %s", comp.NAME, e)
                 continue

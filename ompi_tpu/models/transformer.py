@@ -46,6 +46,16 @@ class Config:
         return self.d_model // self.n_heads
 
 
+# The flagship step on one v5e chip (about 135M params). head_dim=128
+# fills the MXU's 128-lane contraction (hd=64 capped the attention
+# matmuls at half the array). Batch 36 came from a pre-PR-1 sweep through
+# the retired device link (32/36/40/44/48), not measured on the chip:
+# temporaries about 10 GB of the 16 GB HBM.
+FLAGSHIP = Config(vocab=32768, d_model=1024, n_heads=8, n_layers=8,
+                  d_ff=4096, seq_len=1024)
+FLAGSHIP_BATCH = 36
+
+
 def init_params(key, cfg: Config) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
@@ -271,11 +281,9 @@ def make_train_step(mesh, cfg: Config):
             lambda p, g: (p - cfg.lr * g).astype(p.dtype), params, grads)
         return loss, new_params
 
-    from ompi_tpu.parallel.axes import shard_map_compat
-
-    step = shard_map_compat(step_local, mesh,
-                            (pspecs, tok_spec, tok_spec),
-                            (P(), pspecs))
+    step = jax.shard_map(step_local, mesh=mesh,
+                         in_specs=(pspecs, tok_spec, tok_spec),
+                         out_specs=(P(), pspecs))
     jitted = jax.jit(step)
 
     def place(params, tokens, targets):

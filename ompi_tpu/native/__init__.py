@@ -12,6 +12,7 @@ Python rank and a C++ rank can share one ring.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -30,12 +31,37 @@ _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
 
 
-def compile_so(cmd_prefix, srcs, dest, timeout=180, on_error=None):
+def _digest(cmd_prefix, srcs) -> str:
+    """Hash of the compile command and the sources' contents: the key a
+    built library is reused under (an mtime says nothing about a library
+    that came along with a copied tree)."""
+    h = hashlib.sha256("\0".join(cmd_prefix).encode())
+    for p in srcs:
+        with open(p, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def is_built(cmd_prefix, srcs, dest) -> bool:
+    """True when ``dest`` was built by compile_so from exactly these
+    sources with this command (its stamp file holds their digest)."""
+    try:
+        with open(dest + ".sha256") as f:
+            stamp = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(dest) and stamp == _digest(cmd_prefix, srcs)
+
+
+def compile_so(cmd_prefix, srcs, dest, timeout=180, on_error=None,
+               deps=()):
     """Race-safe on-demand compile shared by every native lib: build to
     a private temp file in dest's directory, atomically rename into
     place (last writer wins; identical content makes the race
-    harmless). Returns dest or None; failures (including an unwritable
-    destination directory) go through ``on_error(message)``."""
+    harmless), then stamp it with the digest of the command, ``srcs``
+    and ``deps`` (headers) for is_built. Returns dest or None; failures
+    (including an unwritable destination directory) go through
+    ``on_error(message)``."""
     report = on_error or (lambda m: get_logger("native").warning("%s", m))
     try:
         fd, tmp = tempfile.mkstemp(suffix=".so",
@@ -49,6 +75,9 @@ def compile_so(cmd_prefix, srcs, dest, timeout=180, on_error=None):
                        check=True, capture_output=True, text=True,
                        timeout=timeout)
         os.rename(tmp, dest)
+        with open(tmp, "w") as f:
+            f.write(_digest(cmd_prefix, list(srcs) + list(deps)))
+        os.rename(tmp, dest + ".sha256")
         return dest
     except (subprocess.SubprocessError, OSError) as e:
         detail = getattr(e, "stderr", "") or str(e)
@@ -60,10 +89,13 @@ def compile_so(cmd_prefix, srcs, dest, timeout=180, on_error=None):
         return None
 
 
+_CMD = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17"]
+
+
 def _build() -> bool:
     log = get_logger("native")
     return compile_so(
-        ["g++", "-O2", "-shared", "-fPIC", "-std=c++17"], _SRCS, _SO,
+        _CMD, _SRCS, _SO,
         timeout=120,
         on_error=lambda m: log.warning(
             "%s (falling back to Python)", m)) is not None
@@ -76,10 +108,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        src_mtime = max(os.path.getmtime(p) for p in _SRCS)
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < src_mtime:
-            if not _build():
-                return None
+        if not is_built(_CMD, _SRCS, _SO) and not _build():
+            return None
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:

@@ -44,24 +44,16 @@ NEG_BIG = -1e30
 
 def _vma_union(*xs):
     """Union of the operands' varying-mesh-axes sets (shard_map's vma
-    tracking), or None outside a vma-checked context."""
-    try:
-        out = frozenset()
-        for x in xs:
-            out |= frozenset(jax.typeof(x).vma)
-        return out
-    except (AttributeError, TypeError):
-        return None
+    tracking; empty outside shard_map)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _pvary_to(x, vma):
-    missing = tuple(vma - frozenset(jax.typeof(x).vma))
-    return lax.pvary(x, missing) if missing else x
+    missing = tuple(vma - jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _sds(shape, dtype, vma):
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -385,10 +377,13 @@ def _flash_bwd(sm_scale, interpret, layout, res, g):
     delta8 = jnp.broadcast_to(delta[:, None, :], lse8.shape)
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, kf, kt, do3, lse8, delta8,
                               sm_scale, bq, bk, interpret)
-    zero = jnp.zeros((1, 1), jnp.float32)
+    # the flags' zero cotangents keep the flags' own type: in the ring
+    # they vary over the sp axis (axis_index), and a plain zeros((1, 1))
+    # would not match it under shard_map
     return (_from3(dq3, B, H, layout).astype(q3.dtype),
             _from3(dk3, B, H, layout).astype(k3.dtype),
-            _from3(dv3, B, H, layout).astype(v3.dtype), zero, zero)
+            _from3(dv3, B, H, layout).astype(v3.dtype),
+            jnp.zeros_like(kf), jnp.zeros_like(kt))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

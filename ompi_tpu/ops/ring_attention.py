@@ -118,19 +118,15 @@ def _lax_block(q, k, v, keep_full, keep_tri, sm_scale, mxu_dtype,
 
 
 def use_flash_default(q_shape, k_shape, layout: str = "bthd") -> bool:
-    """Pick the Pallas kernel when running on a real TPU and the shapes
-    tile cleanly; the lax path covers everything else (CPU meshes, odd
-    shapes)."""
+    """Pick the Pallas kernel when the default backend is a TPU and the
+    shapes tile cleanly; the lax path covers everything else (CPU meshes,
+    odd shapes)."""
     import jax
 
     from ompi_tpu.ops.flash_attention import flash_supported
 
-    try:
-        kind = getattr(jax.devices()[0], "device_kind", "")
-    except Exception:
-        return False
-    return "TPU" in str(kind).upper() and flash_supported(q_shape, k_shape,
-                                                          layout)
+    return jax.default_backend() == "tpu" and flash_supported(
+        q_shape, k_shape, layout)
 
 
 def ring_attention(q, k, v, axis_name: str, sp_size: int,
@@ -247,9 +243,9 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
     def local(qb, kb, vb):
         return ring_attention(qb, kb, vb, axis_name, sp, causal=causal)
 
-    from ompi_tpu.parallel.axes import shard_map_compat
-
-    sm = shard_map_compat(local, mesh, (spec, spec, spec), spec)
+    sm = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec),
+                       out_specs=spec)
     sharding = NamedSharding(mesh, spec)
     q = jax.device_put(q, sharding)
     k = jax.device_put(k, sharding)

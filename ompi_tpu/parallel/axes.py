@@ -20,19 +20,6 @@ from typing import Optional, Sequence, Tuple, Union
 AxisName = Union[str, Tuple[str, ...]]
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (single shared fallback — every
-    module that builds shard_map programs routes through here)."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm  # pragma: no cover
-
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def allreduce(x, axis: AxisName, op: str = "sum"):
     """MPI_Allreduce inside shard_map. op: sum|max|min|mean."""
     from jax import lax
@@ -130,11 +117,6 @@ def rank(axis: AxisName):
 
 def size(axis: AxisName) -> int:
     """MPI_Comm_size along an axis (static)."""
-    import jax
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    if hasattr(jax.core, "get_axis_env_size"):  # pragma: no cover
-        return jax.core.get_axis_env_size(axis)
-    return int(lax.psum(1, axis))  # pragma: no cover - last resort
+    return lax.axis_size(axis)

@@ -3,7 +3,7 @@
 ROADMAP item 5: production serving means background planes — diskless
 checkpoint replication (tag -4600), metrics shipping (-4500), respawn
 state transfer — share wires with latency-critical collectives. This
-module owns the class taxonomy and the classification policy; the tcp
+module owns the class scheme and the classification policy; the tcp
 btl (the shaped transport) owns the per-class send scheduler, and the
 pml stamps the class into a spare bit-field of the frame header (bits
 6-7 of the kind byte, NORMAL=0 so an unshaped job's wire format is

@@ -65,10 +65,10 @@ def _lib_dirs() -> List[str]:
 
 
 def build_capi(cc: str = "cc") -> Optional[str]:
-    """Compile libompi_tpu_c.so if stale (vs BOTH sources — a header
-    edit must rebuild or the lib's struct offsets go stale); returns
-    the path or None. Falls back to a per-user cache dir when the
-    package directory is read-only."""
+    """Compile libompi_tpu_c.so unless one built from exactly these
+    sources exists (BOTH sources — a header edit must rebuild or the
+    lib's struct offsets go stale); returns the path or None. Falls back
+    to a per-user cache dir when the package directory is read-only."""
     srcs = [_CAPI_SRC, _CAPI_HDR]
     missing = [s for s in srcs if not os.path.exists(s)]
     if missing:
@@ -76,16 +76,14 @@ def build_capi(cc: str = "cc") -> Optional[str]:
             "mpicc: binding sources missing (%s) — reinstall with the "
             "package data intact\n" % ", ".join(missing))
         return None
-    src_mtime = max(os.path.getmtime(s) for s in srcs)
-    for d in _lib_dirs():
-        so = os.path.join(d, "libompi_tpu_c.so")
-        if _safe_dir(d) and os.path.exists(so) and \
-                os.path.getmtime(so) >= src_mtime:
-            return so
-    from ompi_tpu.native import compile_so
+    from ompi_tpu.native import compile_so, is_built
 
     cmd = [cc, "-O2", "-shared", "-fPIC", f"-I{_NATIVE}"] + \
         _python_embed_flags()
+    for d in _lib_dirs():
+        so = os.path.join(d, "libompi_tpu_c.so")
+        if _safe_dir(d) and is_built(cmd, srcs, so):
+            return so
     for d in _lib_dirs():
         try:
             os.makedirs(d, mode=0o700, exist_ok=True)
@@ -97,6 +95,7 @@ def build_capi(cc: str = "cc") -> Optional[str]:
             continue
         return compile_so(cmd, [_CAPI_SRC],
                           os.path.join(d, "libompi_tpu_c.so"),
+                          deps=[_CAPI_HDR],
                           on_error=lambda m: sys.stderr.write(
                               f"mpicc: {m}\n"))
     sys.stderr.write("mpicc: no writable owner-only directory for "

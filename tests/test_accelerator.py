@@ -146,6 +146,44 @@ def test_null_component_forced():
         accel_base._reset_selection()
 
 
+class _Dev:
+    def __init__(self, kind, platform="tpu"):
+        self.device_kind = kind
+        self.platform = platform
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v9 experimental"])
+def test_peaks_published_or_error(kind):
+    """A TPU kind with a published peak gets it; any other is an error,
+    never a default."""
+    from ompi_tpu.accelerator.tpu import PEAKS, peaks
+
+    if kind in PEAKS:
+        assert peaks(_Dev(kind)) == (197e12, 819.0)
+    else:
+        with pytest.raises(MPIError, match="no published peaks"):
+            peaks(_Dev(kind))
+
+
+def test_device_bringup_failure_is_fatal(monkeypatch):
+    """jax present but its devices failing must raise, not fall back
+    to the host-only null component."""
+    from ompi_tpu.accelerator import tpu as accel_tpu
+    from ompi_tpu.mca.component import ComponentFatal
+
+    def broken():
+        raise RuntimeError("TPU initialization failed")
+
+    monkeypatch.setattr(accel_tpu, "JaxAccelerator", broken)
+    with pytest.raises(ComponentFatal, match="TPU initialization failed"):
+        accelerator_framework.select_all()
+
+
+def test_synchronize_all_devices(mod):
+    mod.synchronize()  # drains every device's queue, not just the first
+    mod.synchronize(jnp.ones(2))
+
+
 def test_accelerator_procmode():
     """Device buffers between real ranks (VERDICT r1 item 4 done-criterion:
     a process-mode send/allreduce of a jax array end-to-end)."""

@@ -1,0 +1,107 @@
+"""Compiles of the main path for a described TPU v5e (no chip attached).
+
+The TPU compiler is installed here and compiles for a topology it is
+only told about: what it refuses here (a tile it cannot lay out, a
+cotangent of the wrong type, a program that does not fit) costs no chip
+time. Nothing runs, so these say nothing of results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every xdist worker
+imports this file.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+SHAPE = (36, 8, 1024, 128)  # the flagship step's per-layer q/k/v, bhtd
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache(topo):
+    """A chip compile is written to the persistent cache but cannot be
+    read back without a chip: keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_compile_cache):
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+
+def _n_kernels(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _qkv(sharding):
+    return [jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16, sharding=sharding)
+            for _ in range(3)]
+
+
+def _attend(q, k, v):
+    from ompi_tpu.ops.flash_attention import flash_block
+
+    out, _ = flash_block(q, k, v, False, True, layout="bhtd")
+    return out
+
+
+def test_flash_forward_compiles(one_chip):
+    compiled = jax.jit(_attend).lower(*_qkv(one_chip)).compile()
+    assert _n_kernels(compiled) == 1
+
+
+def test_flash_backward_compiles(one_chip):
+    grad = jax.grad(lambda q, k, v: jnp.sum(_attend(q, k, v)),
+                    argnums=(0, 1, 2))
+    compiled = jax.jit(grad).lower(*_qkv(one_chip)).compile()
+    # forward + the dq and dk/dv kernels of the backward
+    assert _n_kernels(compiled) == 3
+
+
+def test_train_step_1x2x2_compiles(topo, no_compile_cache, monkeypatch):
+    """The four-chip dp x sp x tp = 1x2x2 step at full width, two layers:
+    ring attention's flash backward must give the sp-varying keep flags
+    cotangents of their own type."""
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.ops import ring_attention
+
+    # the backend here is the CPU, so the default would pick the lax path
+    monkeypatch.setattr(ring_attention, "use_flash_default",
+                        lambda *a, **k: True)
+    cfg = dataclasses.replace(tfm.FLAGSHIP, n_layers=2)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2), ("dp", "sp", "tp"))
+    step, _ = tfm.make_train_step(mesh, cfg)
+    params = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        params, tfm.param_specs(cfg))
+    toks = jax.ShapeDtypeStruct((8, cfg.seq_len), jnp.int32,
+                                sharding=NamedSharding(mesh, P("dp", "sp")))
+    compiled = step.lower(params, toks, toks).compile()
+    # per layer, each of the 2 ring steps runs forward + dq + dk/dv
+    assert _n_kernels(compiled) == 2 * 3 * cfg.n_layers

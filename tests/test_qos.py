@@ -45,9 +45,9 @@ def _shape_cvars():
     set_var("btl_tcp", "shape_max_defer_bytes", 4 << 20)
     set_var("btl_tcp", "shape_weights", "8,4,1")
     set_var("btl_tcp", "shape_quantum_bytes", 1 << 16)
-    set_var("qos", "tag_map",
-            "-4600:bulk,-4500:bulk,-4242:latency,-4243:latency,"
-            "-4244:latency,-4245:latency")
+    # the registered default: a later test file on this worker (the
+    # linkmodel probe tag, -4900) reads it
+    set_var("qos", "tag_map", all_vars()["qos_tag_map"].default)
     qos.reset_for_testing()
 
 

@@ -87,8 +87,6 @@ def test_multi_chunk_flash_matches_dense():
 
     from ompi_tpu.ops.ring_attention import (
         reference_attention, ring_attention)
-    from ompi_tpu.parallel.axes import shard_map_compat
-
     B, S, H, D = 2, 32, 4, 16
     key = jax.random.PRNGKey(3)
     q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
@@ -101,7 +99,9 @@ def test_multi_chunk_flash_matches_dense():
     def local(qb, kb, vb):
         return ring_attention(qb, kb, vb, "sp", 4, causal=True, chunk=2)
 
-    fn = jax.jit(shard_map_compat(local, mesh, (spec,) * 3, spec))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(spec,) * 3,
+                               out_specs=spec))
     got = np.asarray(fn(q, k, v))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 

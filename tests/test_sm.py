@@ -33,6 +33,24 @@ def test_native_library_builds():
     assert NATIVE
 
 
+def test_native_rebuild_keyed_on_source_hash(tmp_path):
+    """A library is reused only when its stamp matches the sources it
+    is built from; a copied-in library with other sources, or none, is
+    rebuilt whatever its mtime."""
+    from ompi_tpu.native import compile_so, is_built
+
+    src = tmp_path / "lib.c"
+    src.write_text("int f(void) { return 1; }\n")
+    dest = str(tmp_path / "lib.so")
+    cmd = ["cc", "-shared", "-fPIC"]
+    assert not is_built(cmd, [str(src)], dest)
+    assert compile_so(cmd, [str(src)], dest) == dest
+    assert is_built(cmd, [str(src)], dest)
+    src.write_text("int f(void) { return 2; }\n")
+    assert not is_built(cmd, [str(src)], dest)
+    assert not is_built(cmd + ["-O2"], [str(src)], dest)
+
+
 def test_ring_roundtrip(ring):
     assert ring.push(b"HDRX", b"payload") == 1
     assert ring.used() > 0

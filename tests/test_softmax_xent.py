@@ -55,8 +55,6 @@ def test_sharded_grad_matches_single():
     cross-shard sum (the explicit psum in _xent_bwd)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ompi_tpu.parallel.axes import shard_map_compat
-
     devs = jax.devices()
     if len(devs) < 4:
         pytest.skip("needs 4 virtual devices")
@@ -72,10 +70,9 @@ def test_sharded_grad_matches_single():
 
         return lax.psum(loss, ("dp", "sp")), gx, gw
 
-    sm = shard_map_compat(
-        local, mesh,
-        (P("dp", "sp", None), P(), P("dp", "sp")),
-        (P(), P("dp", "sp", None), P()))
+    sm = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("dp", "sp", None), P(), P("dp", "sp")),
+                       out_specs=(P(), P("dp", "sp", None), P()))
     loss_sh, gx_sh, gw_sh = jax.jit(sm)(x, w, t)
 
     loss1 = reference_xent_sum(x, w, t)

@@ -102,10 +102,10 @@ def main() -> int:
                            jax.tree_util.tree_leaves(params))
             fl = 6.0 * n_params * B * T \
                 + 12.0 * cfg.n_layers * T * cfg.d_model * B * T
-            from bench import _peak_for
+            from ompi_tpu.accelerator.tpu import peaks
 
-            peak = _peak_for(getattr(dev, "device_kind", ""))
-            mfu = f"mfu={fl/ts/peak:.3f}" if peak else "mfu=n/a (not a TPU)"
+            mfu = (f"mfu={fl/ts/peaks(dev)[0]:.3f}"
+                   if dev.platform == "tpu" else "mfu=n/a (not a TPU)")
             print(f"step {label}:  {ts*1e3:7.1f} ms  {mfu}  "
                   f"temp={mem.temp_size_in_bytes/2**30:.2f}GB",
                   file=sys.stderr)

@@ -138,8 +138,6 @@ def in_situ() -> int:
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ompi_tpu.ops.ring_attention import ring_attention
-    from ompi_tpu.parallel.axes import shard_map_compat
-
     B, H, T, D = 32, 16, 1024, 64
     reps = 16
     rtt = _scalar_time(jax.jit(lambda x: jnp.sum(x)),
@@ -162,7 +160,9 @@ def in_situ() -> int:
                               layout="bhtd")
 
     spec = P(None, None, "sp", None)
-    sm = shard_map_compat(attn_local, mesh, (spec, spec, spec), spec)
+    sm = jax.shard_map(attn_local, mesh=mesh,
+                       in_specs=(spec, spec, spec),
+                       out_specs=spec)
 
     def grad_step(q_, k_, v_):
         def f(qq):

@@ -39,9 +39,7 @@ def main() -> int:
     dev = jax.devices()[0]
     print("device:", getattr(dev, "device_kind", dev), file=sys.stderr)
 
-    # head_dim=128 (8 heads): fills the MXU contraction lanes (r5)
-    cfg = tfm.Config(vocab=32768, d_model=1024, n_heads=8,
-                     n_layers=8, d_ff=4096, seq_len=1024)
+    cfg = tfm.FLAGSHIP
     batch = 32
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1),
@@ -76,7 +74,6 @@ def main() -> int:
               file=sys.stderr)
         return t_step
 
-    from ompi_tpu.parallel.axes import shard_map_compat
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     pspecs = tfm.param_specs(cfg)
@@ -147,9 +144,9 @@ def main() -> int:
                 lambda x, gr: (x - cfg.lr * gr).astype(x.dtype), p, grads)
             return loss, newp
 
-        return shard_map_compat(step_local, mesh,
-                                (pspecs, tok_spec, tok_spec),
-                                (P(), pspecs))
+        return jax.shard_map(step_local, mesh=mesh,
+                             in_specs=(pspecs, tok_spec, tok_spec),
+                             out_specs=(P(), pspecs))
 
     params_p = jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
