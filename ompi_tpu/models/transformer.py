@@ -142,6 +142,7 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
     tp-replicated); attention rotates K/V over 'sp'. With in_mesh=False
     this is the plain single-device forward.
     """
+    import jax
     import jax.numpy as jnp
 
     from ompi_tpu.ops.mxu import einsum_bf16
@@ -160,61 +161,61 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
     x = params["embed"][tokens] + params["pos"][pos_idx][None]
 
     def block(x, blk):
-        h = _ln(x, blk["ln1"])
-        w_qkv = blk["qkv"]  # local [D, H/tp, 3*hd]
-        # three projections emitted straight into the attention kernel's
-        # native [B, H, T, hd] layout: a fused qkv einsum + split costs a
-        # strided-slice relayout of 3x128MB per block (measured +8.7ms per
-        # layer on v5e); separate slices of the weight are free
-        hb = h.astype(jnp.bfloat16)
-        wb = w_qkv.astype(jnp.bfloat16)
-        # bf16 q/k/v via einsum_bf16: the attention kernel consumes bf16
-        # tiles anyway, and keeping the projections (= the kernel's saved
-        # residuals) in bf16 halves their HBM footprint — at the flagship
-        # shape the f32 version sat on the 15.75GB ceiling and XLA
-        # spilled (r4 ablation: attention cost 178ms in-model vs 87ms
-        # isolated); the backward transpose dots still accumulate f32
-        q = einsum_bf16("btd,dhf->bhtf", hb, wb[..., :hd])
-        k = einsum_bf16("btd,dhf->bhtf", hb, wb[..., hd:2 * hd])
-        v = einsum_bf16("btd,dhf->bhtf", hb, wb[..., 2 * hd:])
-        if in_mesh:
-            # full-tile chunk: the flash/recompute backward keeps the
-            # dense tile memory-safe; long-seq configs shrink the tile
-            # via the chunk arg (lax fallback only)
-            att = ring_attention(q, k, v, "sp", sp, causal=causal_ring,
-                                 mxu_dtype=jnp.bfloat16, chunk=T,
-                                 layout="bhtd")
-        else:
-            from ompi_tpu.ops.ring_attention import reference_attention
+        with jax.named_scope("attention"):
+            h = _ln(x, blk["ln1"])
+            w_qkv = blk["qkv"]  # local [D, H/tp, 3*hd]
+            # three projections emitted straight into the attention kernel's
+            # native [B, H, T, hd] layout: a fused qkv einsum + split costs a
+            # strided-slice relayout of 3x128MB per block (measured +8.7ms per
+            # layer on v5e); separate slices of the weight are free
+            hb = h.astype(jnp.bfloat16)
+            wb = w_qkv.astype(jnp.bfloat16)
+            # bf16 q/k/v via einsum_bf16: the attention kernel consumes bf16
+            # tiles anyway, and keeping the projections (= the kernel's saved
+            # residuals) in bf16 halves their HBM footprint — at the flagship
+            # shape the f32 version sat on the 15.75GB ceiling and XLA
+            # spilled (r4 ablation: attention cost 178ms in-model vs 87ms
+            # isolated); the backward transpose dots still accumulate f32
+            q = einsum_bf16("btd,dhf->bhtf", hb, wb[..., :hd])
+            k = einsum_bf16("btd,dhf->bhtf", hb, wb[..., hd:2 * hd])
+            v = einsum_bf16("btd,dhf->bhtf", hb, wb[..., 2 * hd:])
+            if in_mesh:
+                # full-tile chunk: the flash/recompute backward keeps the
+                # dense tile memory-safe; long-seq configs shrink the tile
+                # via the chunk arg (lax fallback only)
+                att = ring_attention(q, k, v, "sp", sp, causal=causal_ring,
+                                     mxu_dtype=jnp.bfloat16, chunk=T,
+                                     layout="bhtd")
+            else:
+                from ompi_tpu.ops.ring_attention import reference_attention
 
-            tr = lambda a: jnp.transpose(a, (0, 2, 1, 3))
-            att = tr(reference_attention(tr(q), tr(k), tr(v), causal=True))
-        # row-parallel output projection contracted directly over (h, d):
-        # no [B,T,H*hd] relayout of the attention output
-        wo = blk["wo"].reshape(h_local, hd, cfg.d_model)
-        out = jnp.einsum("bhtf,hfd->btd", att.astype(jnp.bfloat16),
-                         wo.astype(jnp.bfloat16),
-                         preferred_element_type=jnp.float32)
-        if in_mesh:
-            out = axes.allreduce(out, "tp")  # MPI_Allreduce on ICI
-        x = x + out
+                tr = lambda a: jnp.transpose(a, (0, 2, 1, 3))
+                att = tr(reference_attention(tr(q), tr(k), tr(v), causal=True))
+            # row-parallel output projection contracted directly over (h, d):
+            # no [B,T,H*hd] relayout of the attention output
+            wo = blk["wo"].reshape(h_local, hd, cfg.d_model)
+            out = jnp.einsum("bhtf,hfd->btd", att.astype(jnp.bfloat16),
+                             wo.astype(jnp.bfloat16),
+                             preferred_element_type=jnp.float32)
+            if in_mesh:
+                out = axes.allreduce(out, "tp")  # MPI_Allreduce on ICI
+            x = x + out
 
-        h2 = _ln(x, blk["ln2"])
-        # the saved relu residual ([B,T,d_ff], the layer's largest
-        # activation) is stored bf16 (half-size) with f32-accumulated
-        # backward via einsum_bf16
-        ff1 = jnp.maximum(einsum_bf16("btd,df->btf",
-                                      h2.astype(jnp.bfloat16),
-                                      blk["w1"].astype(jnp.bfloat16)),
-                          jnp.bfloat16(0))
-        ff = _mm(ff1, blk["w2"])
-        if in_mesh:
-            ff = axes.allreduce(ff, "tp")
-        return x + ff
+        with jax.named_scope("mlp"):
+            h2 = _ln(x, blk["ln2"])
+            # the saved relu residual ([B,T,d_ff], the layer's largest
+            # activation) is stored bf16 (half-size) with f32-accumulated
+            # backward via einsum_bf16
+            ff1 = jnp.maximum(einsum_bf16("btd,df->btf",
+                                          h2.astype(jnp.bfloat16),
+                                          blk["w1"].astype(jnp.bfloat16)),
+                              jnp.bfloat16(0))
+            ff = _mm(ff1, blk["w2"])
+            if in_mesh:
+                ff = axes.allreduce(ff, "tp")
+            return x + ff
 
     if cfg.remat:
-        import jax
-
         block = jax.checkpoint(block)
     for blk in params["blocks"]:
         x = block(x, blk)
@@ -241,11 +242,14 @@ def forward(params, tokens, cfg: Config):
 
 def _loss_local(params, tokens, targets, cfg: Config, tp: int, sp: int,
                 denom: float):
+    import jax
+
     from ompi_tpu.ops.softmax_xent import softmax_xent_sum
 
     x = features_local(params, tokens, cfg, tp=tp, sp=sp, in_mesh=True)
-    return softmax_xent_sum(x, params["embed"], targets, 128,
-                            ("dp", "sp")) / denom
+    with jax.named_scope("loss"):
+        return softmax_xent_sum(x, params["embed"], targets, 128,
+                                ("dp", "sp")) / denom
 
 
 def make_train_step(mesh, cfg: Config):

@@ -177,6 +177,7 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, block_q: int,
             _sds((BH, 8, Tq), jnp.float32, vma),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(kf, kt, q3, k3, v3)
 
 
@@ -297,6 +298,7 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=_sds((BH, Tq, D), jnp.float32, vma),
         interpret=interpret,
+        name="flash_dq",
     )(kf, kt, q3, k3, v3, do3, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
@@ -319,6 +321,7 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
             _sds((BH, Tk, D), jnp.float32, vma),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(kf, kt, q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 

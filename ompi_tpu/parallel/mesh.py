@@ -164,13 +164,16 @@ class XlaComm(Intracomm):
 
     def _hot(self, verb: str, fn, *args):
         """Shared fast-path epilogue: SPC bump + compile-cache-hit
-        count + the comm.<verb> span (one branch when tracing is off —
-        the dispatch-tax budget of the resolved table)."""
-        spc.record(verb)
-        self._cstats.hits += 1
+        count + the executable's call, all inside the comm.<verb> span
+        (one branch when tracing is off — the dispatch-tax budget of the
+        resolved table). Only the verb's fast-table lookup precedes it."""
         if _tr.enabled():
             with _tr.span("comm." + verb, cat="comm"):
+                spc.record(verb)
+                self._cstats.hits += 1
                 return fn(*args)
+        spc.record(verb)
+        self._cstats.hits += 1
         return fn(*args)
 
     def _promote(self, fast_key, exec_key, wrap=None):

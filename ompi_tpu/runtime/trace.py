@@ -13,10 +13,17 @@ Design here:
   is a GIL-atomic list store — no lock, no allocation beyond the event
   tuple; when the ring wraps, the OLDEST events are overwritten and
   counted as dropped.
-- **Gated by one attribute load**: ``trace.enabled()`` reads the live
-  MCA Var slot (same discipline as spc.record — set_var stays live).
-  Instrumentation sites guard with ``if trace.enabled():`` so the
-  disabled fast path costs one branch.
+- **Two sinks, one gate**: the rings above, on while the live MCA Var
+  ``trace_enable`` is (same discipline as spc.record — set_var stays
+  live); and the JAX profiler, whenever a profiler session is
+  collecting (``jax.profiler.start_trace``, TensorBoard, Perfetto). A
+  span there is a ``jax.profiler.TraceAnnotation`` of the same name,
+  stamped on the profiler's clock on the calling thread's line, so it
+  lies on one timeline with the device ops; ``step`` is a
+  ``StepTraceAnnotation``. ``trace.enabled()`` is true while either
+  sink is on; instrumentation sites guard with ``if trace.enabled():``
+  so the disabled fast path costs one branch, one attribute load and
+  the profiler's own C++ check.
 - **MPI_T integration**: span begin/end also fire the ``trace_span_begin``
   / ``trace_span_end`` MPI_T event types (mpit.py), so a tool attached
   through the MPI_T surface sees the identical stream without touching
@@ -28,8 +35,10 @@ Design here:
   onto a shared timeline using mpisync clock offsets; timestamps are
   ``time.monotonic_ns`` so the offsets apply directly.
 
-Enable with ``OMPI_TPU_MCA_trace_enable=1`` (or ``--mca trace_enable 1``
-through mpirun, or ``set_var("trace", "enable", True)``).
+Enable the rings with ``OMPI_TPU_MCA_trace_enable=1`` (or ``--mca
+trace_enable 1`` through mpirun, or ``set_var("trace", "enable",
+True)``); the profiler sink needs no var. Only spans reach the
+profiler: retroactive spans, instants and counters are ring-only.
 """
 
 from __future__ import annotations
@@ -37,6 +46,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -74,10 +84,31 @@ _cap_var = register_var(
     level=5)
 
 
+def _profiler_probe() -> bool:
+    """The profiler sink's gate until ``jax.profiler`` is loaded: no
+    session can be collecting before then. Once it is, this rebinds
+    ``_profiling`` to ``TraceAnnotation.is_enabled`` (a C++ static)
+    and ``_Annotation``/``_StepAnnotation`` to the profiler's context
+    managers — the package itself never imports JAX for tracing."""
+    global _profiling, _Annotation, _StepAnnotation
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not hasattr(prof, "StepTraceAnnotation"):
+        return False  # absent, or still being imported
+    _Annotation = prof.TraceAnnotation
+    _StepAnnotation = prof.StepTraceAnnotation
+    _profiling = _Annotation.is_enabled
+    return _profiling()
+
+
+_profiling = _profiler_probe
+_Annotation = _StepAnnotation = None
+
+
 def enabled() -> bool:
-    """One attribute load off the live Var (spc.record discipline) —
+    """True while either sink is on: one attribute load off the live Var
+    (spc.record discipline), then the profiler's own check —
     instrumentation sites guard their span setup with this."""
-    return _enable_var._value
+    return _enable_var._value or _profiling()
 
 
 def now() -> int:
@@ -138,27 +169,52 @@ def _record(ph: str, name: str, cat: str, ts: int,
 # ------------------------------------------------------------------ spans
 class span:
     """``with trace.span("coll.xla.dispatch", cat="coll", verb="allreduce")``
-    — records a B event at enter, an E at exit, and mirrors both onto the
-    MPI_T event stream. Call sites guard with ``if trace.enabled():`` so
-    construction only happens when tracing is on; the span itself records
-    unconditionally (a mid-span disable must not break B/E pairing)."""
+    — into the rings, a B event at enter and an E at exit, mirrored onto
+    the MPI_T event stream; into a collecting profiler, a
+    ``TraceAnnotation(name, **args)``. Call sites guard with ``if
+    trace.enabled():`` so construction only happens when tracing is on.
+    The sinks are chosen once, at enter, and the same ones closed at
+    exit: a mid-span toggle of either must not break B/E pairing."""
 
-    __slots__ = ("name", "cat", "args")
+    __slots__ = ("name", "cat", "args", "_ring", "_ann")
 
     def __init__(self, name: str, cat: str = "", **args: Any):
         self.name = name
         self.cat = cat
         self.args = args or None
 
+    def _annotation(self):
+        return _Annotation(self.name, **(self.args or {}))
+
     def __enter__(self):
-        _record("B", self.name, self.cat, time.monotonic_ns(), self.args)
-        _emit_mpit("span_begin", self.name, self.cat)
+        self._ring = _enable_var._value
+        if self._ring:
+            _record("B", self.name, self.cat, time.monotonic_ns(),
+                    self.args)
+            _emit_mpit("span_begin", self.name, self.cat)
+        self._ann = None
+        if _profiling():
+            self._ann = self._annotation()
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        _record("E", self.name, self.cat, time.monotonic_ns(), None)
-        _emit_mpit("span_end", self.name, self.cat)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._ring:
+            _record("E", self.name, self.cat, time.monotonic_ns(), None)
+            _emit_mpit("span_end", self.name, self.cat)
         return False
+
+
+class _StepSpan(span):
+    """``trace.step``'s span: a ``StepTraceAnnotation`` in the profiler,
+    so its step view cuts where tools/mpicrit.py does."""
+
+    __slots__ = ()
+
+    def _annotation(self):
+        return _StepAnnotation(self.name, step_num=self.args["step"])
 
 
 def step(n: int) -> "span":
@@ -169,14 +225,17 @@ def step(n: int) -> "span":
     same ``n`` (serve/harness drives this automatically from its state
     step counter; examples/bench call it around their own loops). Call
     sites guard with ``if trace.enabled():`` like any span site."""
-    return span("trace.step", cat="step", step=int(n))
+    return _StepSpan("trace.step", cat="step", step=int(n))
 
 
 def record_span(name: str, t0: int, t1: int, cat: str = "",
                 **args: Any) -> None:
     """Retroactive span from saved ``now()`` timestamps — for sites that
     only decide to record after the fact (a progress iteration that
-    handled zero events is noise; one that delivered is signal)."""
+    handled zero events is noise; one that delivered is signal).
+    Ring-only: the profiler takes no event after the fact."""
+    if not _enable_var._value:
+        return
     _record("B", name, cat, t0, args or None)
     _record("E", name, cat, t1, None)
     _emit_mpit("span_begin", name, cat)
@@ -184,13 +243,17 @@ def record_span(name: str, t0: int, t1: int, cat: str = "",
 
 
 def instant(name: str, cat: str = "", **args: Any) -> None:
-    """Point event ("ph": "i") — one-off occurrences, not durations."""
-    _record("i", name, cat, time.monotonic_ns(), args or None)
+    """Point event ("ph": "i") — one-off occurrences, not durations.
+    Ring-only."""
+    if _enable_var._value:
+        _record("i", name, cat, time.monotonic_ns(), args or None)
 
 
 def counter(name: str, value, cat: str = "") -> None:
-    """Counter track ("ph": "C"): Perfetto renders these as a graph."""
-    _record("C", name, cat, time.monotonic_ns(), {name: value})
+    """Counter track ("ph": "C"): Perfetto renders these as a graph.
+    Ring-only."""
+    if _enable_var._value:
+        _record("C", name, cat, time.monotonic_ns(), {name: value})
 
 
 def wrap_span(name: str, cat: str, fn):

@@ -262,3 +262,146 @@ def test_progress_iterations_traced(tracing):
     # engine; either way the pml layers must have recorded
     assert "pml.send" in names
     assert "pml.recv" in names
+
+
+# ------------------------------------------------------ the profiler sink
+def _profile(tmp_path, body):
+    """Run ``body`` under ``jax.profiler.start_trace``; the host events
+    of the thread that ran it, as (name, start, end, stats)."""
+    import glob
+
+    d = str(tmp_path / "prof")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # the host's C++ events only
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    lines = [ln for p in pd.planes if p.name.startswith("/host:")
+             for ln in p.lines]
+    mine = [ln for ln in lines if any(e.name == "unit.outer"
+                                      for e in ln.events)]
+    assert len(mine) == 1, [ln.name for ln in lines]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+             dict(e.stats)) for e in mine[0].events]
+
+
+@pytest.fixture
+def warm_world():
+    """A 4-device world whose allreduce already runs its fast path."""
+    world = mesh_world(jax.devices()[:4])
+    x = world.shard(np.ones((4, 8), np.float32))
+    world.allreduce(x).block_until_ready()
+    world.allreduce(x).block_until_ready()
+    return world, x
+
+
+def test_profiler_sink_alone_puts_the_verb_span_in_the_device_trace(
+        warm_world, tmp_path):
+    """trace_enable off, a profiler collecting: one comm.allreduce in the
+    .xplane.pb, inside the enclosing annotation and holding JAX's
+    dispatch; nothing in the rings, no MPI_T event, no export file."""
+    from ompi_tpu import mpit
+
+    world, x = warm_world
+    trace.reset()
+    assert not trace.enabled()
+    set_var("trace", "dir", str(tmp_path / "rings"))
+    mpit.init_thread()
+    seen = []
+    h = mpit.event_handle_alloc(mpit.event_get_index("trace_span_begin"),
+                                lambda inst: seen.append(inst))
+
+    def body():
+        assert trace.enabled()
+        with jax.profiler.TraceAnnotation("unit.outer"):
+            world.allreduce(x).block_until_ready()
+
+    try:
+        evs = _profile(tmp_path, body)
+        trace._maybe_export()
+    finally:
+        h.free()
+        mpit.finalize()
+        set_var("trace", "dir", "")
+    assert not trace.enabled()
+    (outer,) = [e for e in evs if e[0] == "unit.outer"]
+    spans = [e for e in evs if e[0] == "comm.allreduce"]
+    assert len(spans) == 1
+    (_, s, t, _), = spans
+    assert outer[1] <= s and t <= outer[2]
+    assert any(n.startswith("PjitFunction") and s <= a and b <= t
+               for n, a, b, _ in evs)
+    assert trace.snapshot() == [] and trace.buffered_events() == 0
+    assert seen == []
+    assert not (tmp_path / "rings").exists()
+
+
+def test_both_sinks_get_the_span(tracing, warm_world, tmp_path):
+    world, x = warm_world
+    trace.reset()
+
+    def body():
+        with jax.profiler.TraceAnnotation("unit.outer"):
+            world.allreduce(x).block_until_ready()
+            with trace.step(3):
+                pass
+
+    evs = _profile(tmp_path, body)
+    assert [e[0] for e in evs].count("comm.allreduce") == 1
+    (step,) = [e for e in evs if e[0] == "trace.step"]
+    assert step[3].get("step_num") == 3
+    ring = [(ev[0], ev[2]) for _tid, ev in trace.snapshot()]
+    assert ring.count(("B", "comm.allreduce")) == 1
+    assert ring.count(("E", "comm.allreduce")) == 1
+    assert ("B", "trace.step") in ring
+
+
+def test_neither_sink_records_nothing(warm_world):
+    world, x = warm_world
+    trace.reset()
+    assert not trace.enabled()
+    with trace.span("unit.off", cat="test"):
+        world.allreduce(x).block_until_ready()
+    trace.record_span("unit.after", trace.now(), trace.now())
+    trace.instant("unit.instant")
+    trace.counter("unit.counter", 1)
+    assert trace.snapshot() == [] and trace.buffered_events() == 0
+
+
+def test_ring_only_events_stay_out_of_the_profiler(tmp_path):
+    """Retroactive spans, instants and counters do not reach a profiler;
+    with trace_enable off they record nothing at all."""
+    trace.reset()
+
+    def body():
+        with jax.profiler.TraceAnnotation("unit.outer"):
+            t0 = trace.now()
+            trace.record_span("unit.after", t0, trace.now())
+            trace.instant("unit.instant")
+            trace.counter("unit.counter", 1)
+
+    evs = _profile(tmp_path, body)
+    assert {e[0] for e in evs} == {"unit.outer"}
+    assert trace.buffered_events() == 0
+
+
+def test_span_closes_the_sinks_it_opened(tracing):
+    """A toggle inside a span leaves its B/E pairing whole: the sinks
+    are chosen at enter and closed at exit."""
+    with trace.span("unit.toggle", cat="test"):
+        set_var("trace", "enable", False)
+    set_var("trace", "enable", True)
+    with trace.span("unit.late", cat="test"):
+        pass
+    ring = [(ev[0], ev[2]) for _tid, ev in trace.snapshot()]
+    assert ring == [("B", "unit.toggle"), ("E", "unit.toggle"),
+                    ("B", "unit.late"), ("E", "unit.late")]
+    set_var("trace", "enable", False)
+    trace.reset()
+    with trace.span("unit.off", cat="test"):
+        set_var("trace", "enable", True)
+    assert trace.snapshot() == []
