@@ -29,7 +29,7 @@ Contract (shared with the lax fallback in ring_attention.py):
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import numpy as np
 
@@ -66,7 +66,19 @@ def _pick_blocks(tq: int, tk: int, d: int) -> Tuple[int, int]:
     contraction) the balance flips — 512x512 wins 16% because the
     dynamic causal bounds skip a quarter of the tile walk and the
     epilogue is relatively cheaper (r5 sweep: 2.64 vs 3.14 ms/layer
-    fwd+bwd)."""
+    fwd+bwd).
+
+    The causal walk over these tiles is ``causal_walk``'s: tiles wholly
+    below the diagonal unmasked, and a square diagonal tile in
+    ``_DIAG_SUB``-wide sub-blocks that stop at the diagonal, each
+    masking only its own square. Swept on v5e, one layer's fwd + dq +
+    dk/dv at [36, 8, 1024, 128] bf16: whole masked diagonal tiles 4.780
+    ms; off-diagonal tiles unmasked 4.436; diagonal sub-blocks of 256
+    4.382, of 128 4.761 (128-row matmuls cost more than the elements
+    they save, and dk/dv lost at both); with dk/dv scoring keys
+    by queries, so that no matmul takes a transposed operand, 256 gives
+    4.269 and 128 4.252. 64 does not compile: lse rows are lane slices
+    that start at multiples of 128."""
     cap = 1024 if d < 128 else 512
     bq = cap
     while bq > 1 and tq % bq:
@@ -77,81 +89,169 @@ def _pick_blocks(tq: int, tk: int, d: int) -> Tuple[int, int]:
     return bq, bk
 
 
+# a diagonal tile is walked in sub-blocks this wide (or whole, if
+# narrower): the best of the sweep in _pick_blocks' docstring
+_DIAG_SUB = 256
+
+
+class Walk(NamedTuple):
+    """The tile walk of a causal (keep_tri) call, per (b, h)."""
+
+    block_q: int
+    block_k: int
+    sub: int      # diagonal sub-block width; 0 where the split is off
+    visited: int  # score elements each of the three kernels computes
+    masked: int   # of those, the elements that pass through the mask
+
+
+def _tri_tiles(qi, block_q: int, block_k: int):
+    """(tiles wholly at or below the diagonal, tiles touching it) for Q
+    tile ``qi``, counted from KV tile 0 and not capped at the KV tiles."""
+    full = (qi * block_q + 1) // block_k
+    touch = (qi * block_q + block_q + block_k - 1) // block_k
+    return full, touch
+
+
+def causal_walk(tq: int, tk: int, d: int) -> Walk:
+    """The walk the kernels make for a Q shard of ``tq`` rows against a
+    KV shard of ``tk`` with head dim ``d``: the blocks ``_pick_blocks``
+    chooses, the diagonal sub-block (0 where the diagonal tile is not a
+    square of whole 128-lane tiles), and the elements a causal call
+    scores and masks per (b, h), the same in all three kernels. A full
+    (keep_full) call scores tq*tk elements and masks none."""
+    bq, bk = _pick_blocks(tq, tk, d)
+    # lse rows are sliced on lanes: a sub-block spans whole 128-lane tiles
+    square = bq == bk and tq == tk and bq % 128 == 0
+    sub = min(_DIAG_SUB, bq) if square else 0
+    n_kv = tk // bk
+    visited = masked = 0
+    for qi in range(tq // bq):
+        full, touch = (min(x, n_kv) for x in _tri_tiles(qi, bq, bk))
+        visited += full * bq * bk
+        if sub:
+            n = bq // sub
+            visited += sub * sub * n * (n + 1) // 2
+            masked += n * sub * sub
+        else:
+            visited += (touch - full) * bq * bk
+            masked += (touch - full) * bq * bk
+    return Walk(bq, bk, sub, visited, masked)
+
+
+def _causal(s, off, key_axis: int = 1):
+    """Scores ``s`` with every key after its query set to NEG_BIG. Keys
+    run along ``key_axis`` and queries along the other; ``off`` is the
+    first query's index less the first key's."""
+    keys = lax.broadcasted_iota(jnp.int32, s.shape, key_axis)
+    queries = lax.broadcasted_iota(jnp.int32, s.shape, 1 - key_axis) + off
+    return jnp.where(keys <= queries, s, NEG_BIG)
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
 # --------------------------------------------------------------- forward
-def _tile_bounds(kfull, ktri, qi, block_q: int, block_k: int, n_kv: int):
-    """Dynamic KV-tile loop bound for one Q tile: all of them when fully
-    attending, only tiles touching the causal triangle when diagonal,
-    none otherwise. A DYNAMIC fori_loop bound skips irrelevant tiles
+def _q_walk(kfull, ktri, qi, walk: Walk, n_kv: int):
+    """Dynamic KV-tile bounds for one Q tile: tiles [0, n_full) run
+    unmasked and [n_full, hi) masked. All tiles unmasked when fully
+    attending, the tiles touching the causal triangle when diagonal,
+    none otherwise. DYNAMIC fori_loop bounds skip irrelevant tiles
     outright — the r3 kernel wrapped every tile in lax.cond and still
-    paid the full T^2 tile walk."""
-    tri_hi = (qi * block_q + block_q + block_k - 1) // block_k
-    hi = jnp.where(kfull, n_kv, jnp.where(ktri,
-                                          jnp.minimum(tri_hi, n_kv), 0))
-    return hi.astype(jnp.int32)
+    paid the full T^2 tile walk — and split masked from unmasked tiles
+    with no branch per tile (a per-tile lax.cond measured slower on v5e
+    than masking every tile, r4 sweep)."""
+    full, touch = _tri_tiles(qi, walk.block_q, walk.block_k)
+    n_full = jnp.where(kfull, n_kv,
+                       jnp.where(ktri, jnp.minimum(full, n_kv), 0))
+    hi = jnp.where(kfull, n_kv,
+                   jnp.where(ktri, jnp.minimum(touch, n_kv), 0))
+    return n_full.astype(jnp.int32), hi.astype(jnp.int32)
 
 
 def _fwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                block_q: int, block_k: int, n_kv: int, sm_scale: float):
+                walk: Walk, n_kv: int, sm_scale: float):
+    block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
     qi = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
     ktri = kt_ref[0, 0] != 0.0
     q = q_ref[0].astype(jnp.bfloat16)  # [BQ, D]
-    rows = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0)
-    base_cols = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     D = q_ref.shape[-1]
 
-    def scores(i):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.bfloat16)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.bfloat16)
-        s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        return s, vb
+    def scores(qb, lo, width, off=None):
+        """qb's scores against keys [lo, lo + width), causally masked
+        when ``off`` is given, and those keys' values."""
+        kb = k_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
+        vb = v_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
+        s = _dot(qb, kb, ((1,), (1,))) * sm_scale
+        return (s if off is None else _causal(s, off)), vb
 
-    def accumulate(s, vb, carry):
+    def accumulate(parts, carry):
+        """One online-softmax step over the (scores, values) parts of
+        one row block."""
         acc, m, den = carry
-        m_p = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_p)
-        # no second where: masked entries hold NEG_BIG and every row of
-        # an aligned diagonal tile keeps >= 1 column, so exp underflows
-        # masked entries to exactly 0
-        p = jnp.exp(s - m_new)
+        m_new = m
+        for s, _ in parts:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        acc = acc * alpha + lax.dot_general(
-            p.astype(jnp.bfloat16), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        den = den * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc, den = acc * alpha, den * alpha
+        for s, vb in parts:
+            # no second where: masked entries hold NEG_BIG and every row
+            # keeps >= 1 column, so exp underflows them to exactly 0
+            p = jnp.exp(s - m_new)
+            acc = acc + _dot(p.astype(jnp.bfloat16), vb, ((1,), (0,)))
+            den = den + jnp.sum(p, axis=-1, keepdims=True)
         return acc, m_new, den
 
-    def body(i, carry):
-        # one body for every tile: a per-tile lax.cond(full/masked)
-        # measured SLOWER on v5e than just masking (the mask compare is
-        # cheap next to the branch overhead; r4 sweep) — the win comes
-        # from the dynamic loop bound skipping irrelevant tiles
-        s, vb = scores(i)
-        cols = i * block_k + base_cols
-        s = jnp.where(kfull | (cols <= rows), s, NEG_BIG)
-        return accumulate(s, vb, carry)
+    def finish(rows, acc, m, den):
+        o_ref[0, rows, :] = acc / jnp.maximum(den, 1e-30)
+        lse = jnp.where(den[:, 0] > 0.0, m[:, 0] + jnp.log(den[:, 0]),
+                        NEG_BIG)
+        # lse rides in an 8-sublane broadcast layout (BH, 8, Tq): a
+        # (1, BQ) tile would violate the TPU (8, 128) tiling rule
+        lse_ref[0, :, rows] = lax.broadcast_in_dim(lse, (8, lse.shape[0]),
+                                                   (1,))
+
+    def full_body(i, carry):
+        return accumulate([scores(q, i * block_k, block_k)], carry)
+
+    def masked_body(i, carry):
+        off = qi * block_q - i * block_k
+        return accumulate([scores(q, i * block_k, block_k, off)], carry)
 
     acc0 = jnp.zeros((block_q, D), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_BIG, jnp.float32)
     den0 = jnp.zeros((block_q, 1), jnp.float32)
-    hi = _tile_bounds(kfull, ktri, qi, block_q, block_k, n_kv)
-    acc, m, den = lax.fori_loop(0, hi, body, (acc0, m0, den0))
-    o_ref[0] = acc / jnp.maximum(den, 1e-30)
-    lse = jnp.where(den[:, 0] > 0.0, m[:, 0] + jnp.log(den[:, 0]), NEG_BIG)
-    # lse rides in an 8-sublane broadcast layout (BH, 8, Tq): a (1, BQ)
-    # tile would violate the TPU (8, 128) tiling rule
-    lse_ref[0] = lax.broadcast_in_dim(lse, (8, block_q), (1,))
+    n_full, hi = _q_walk(kfull, ktri, qi, walk, n_kv)
+    carry = lax.fori_loop(0, n_full, full_body, (acc0, m0, den0))
+    if not sub:
+        finish(slice(None), *lax.fori_loop(n_full, hi, masked_body, carry))
+        return
+    diag = ktri & ~kfull
+    pl.when(~diag)(lambda: finish(slice(None), *carry))
+
+    @pl.when(diag)
+    def _():
+        # row sub-block r scores keys [0, (r+1)*sub) of the diagonal
+        # tile; only its own sub x sub square crosses the diagonal
+        base = qi * block_q
+        for r in range(block_q // sub):
+            rows = slice(r * sub, (r + 1) * sub)
+            qr = q_ref[0, rows, :].astype(jnp.bfloat16)
+            parts = [scores(qr, base, r * sub)] if r else []
+            parts.append(scores(qr, base + r * sub, sub, 0))
+            finish(rows, *accumulate(parts, tuple(x[rows] for x in carry)))
 
 
-def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, block_q: int,
-              block_k: int, interpret: bool):
+def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
+              interpret: bool):
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
+    block_q, block_k = walk.block_q, walk.block_k
     grid = (BH, Tq // block_q)
-    kern = functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                             n_kv=Tk // block_k, sm_scale=sm_scale)
+    kern = functools.partial(_fwd_kernel, walk=walk, n_kv=Tk // block_k,
+                             sm_scale=sm_scale)
     vma = _vma_union(q3, k3, v3, kf, kt)
     if vma:
         q3, k3, v3, kf, kt = (_pvary_to(x, vma)
@@ -183,97 +283,158 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, block_q: int,
 
 # -------------------------------------------------------------- backward
 def _dq_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, *, block_q: int, block_k: int, n_kv: int,
+               delta_ref, dq_ref, *, walk: Walk, n_kv: int,
                sm_scale: float):
+    block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
     qi = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
     ktri = kt_ref[0, 0] != 0.0
-    q = q_ref[0].astype(jnp.bfloat16)
-    do = do_ref[0].astype(jnp.bfloat16)           # [BQ, D]
-    lse = lse_ref[0, 0, :][:, None]               # [BQ, 1]
-    delta = delta_ref[0, 0, :][:, None]           # [BQ, 1]
-    rows = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0)
-    base_cols = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     D = q_ref.shape[-1]
 
-    def compute(i, dq):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.bfloat16)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.bfloat16)
-        s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        cols = i * block_k + base_cols
-        s = jnp.where(kfull | (cols <= rows), s, NEG_BIG)
+    def rows_of(rows):
+        """q, dO, lse and delta of a row block."""
+        return (q_ref[0, rows, :].astype(jnp.bfloat16),
+                do_ref[0, rows, :].astype(jnp.bfloat16),
+                lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None])
+
+    def grad(qrows, lo, width, off=None):
+        """dq of a row block from keys [lo, lo + width), causally masked
+        when ``off`` is given."""
+        qb, dob, lse, delta = qrows
+        kb = k_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
+        vb = v_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
+        s = _dot(qb, kb, ((1,), (1,))) * sm_scale
+        if off is not None:
+            s = _causal(s, off)
         # exp(NEG_BIG - lse) underflows to 0: masked entries need no
         # second where (lse rows are finite wherever a row attends)
         p = jnp.exp(s - lse)
-        dp = lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + lax.dot_general(ds.astype(jnp.bfloat16), kb,
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        ds = p * (_dot(dob, vb, ((1,), (1,))) - delta)
+        return _dot(ds.astype(jnp.bfloat16), kb, ((1,), (0,)))
 
-    body = compute
+    whole = rows_of(slice(None))
 
-    hi = _tile_bounds(kfull, ktri, qi, block_q, block_k, n_kv)
-    dq = lax.fori_loop(0, hi, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[0] = dq * sm_scale
+    def full_body(i, dq):
+        return dq + grad(whole, i * block_k, block_k)
+
+    def masked_body(i, dq):
+        return dq + grad(whole, i * block_k, block_k,
+                         qi * block_q - i * block_k)
+
+    n_full, hi = _q_walk(kfull, ktri, qi, walk, n_kv)
+    dq = lax.fori_loop(0, n_full, full_body,
+                       jnp.zeros((block_q, D), jnp.float32))
+    if not sub:
+        dq_ref[0] = lax.fori_loop(n_full, hi, masked_body, dq) * sm_scale
+        return
+    diag = ktri & ~kfull
+
+    @pl.when(~diag)
+    def _():
+        dq_ref[0] = dq * sm_scale
+
+    @pl.when(diag)
+    def _():
+        base = qi * block_q
+        for r in range(block_q // sub):
+            rows = slice(r * sub, (r + 1) * sub)
+            qrows = rows_of(rows)
+            dq_r = dq[rows]
+            if r:
+                dq_r = dq_r + grad(qrows, base, r * sub)
+            dq_r = dq_r + grad(qrows, base + r * sub, sub, 0)
+            dq_ref[0, rows, :] = dq_r * sm_scale
 
 
 def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, dk_ref, dv_ref, *, block_q: int, block_k: int,
-                n_q: int, sm_scale: float):
+                delta_ref, dk_ref, dv_ref, *, walk: Walk, n_q: int,
+                sm_scale: float):
+    block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
     ki = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
     ktri = kt_ref[0, 0] != 0.0
     kb = k_ref[0].astype(jnp.bfloat16)            # [BK, D]
     vb = v_ref[0].astype(jnp.bfloat16)
-    base_rows = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1)
     D = kb.shape[-1]
 
-    def compute(i, carry):
-        dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.bfloat16)
-        dob = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.bfloat16)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        rows = i * block_q + base_rows
-        s = jnp.where(kfull | (cols <= rows), s, NEG_BIG)
-        p = jnp.exp(s - lse)  # masked entries underflow to exactly 0
-        pb = p.astype(jnp.bfloat16)
-        dv = dv + lax.dot_general(pb, dob, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + lax.dot_general(ds.astype(jnp.bfloat16), qb,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    def grad(kb, vb, lo, height, off=None, tail=0):
+        """(dk, dv) of keys kb from queries [lo, lo + height), causally
+        masked from key row ``tail`` on when ``off`` is given. The scores
+        are taken transposed, keys by queries, so that no matmul needs a
+        transposed operand and lse and delta are read as the lane rows
+        they are stored in."""
+        qb = q_ref[0, pl.ds(lo, height), :].astype(jnp.bfloat16)
+        dob = do_ref[0, pl.ds(lo, height), :].astype(jnp.bfloat16)
+        lse = lse_ref[0, 0:1, pl.ds(lo, height)]      # [1, height]
+        delta = delta_ref[0, 0:1, pl.ds(lo, height)]
+        st = _dot(kb, qb, ((1,), (1,))) * sm_scale
+        if off is not None:
+            # rows are whole vreg tiles on both sides of ``tail``: the
+            # split and the concatenation move no data
+            sq = _causal(st[tail:], off - tail, key_axis=0)
+            st = jnp.concatenate([st[:tail], sq]) if tail else sq
+        pt = jnp.exp(st - lse)  # masked entries underflow to exactly 0
+        dv = _dot(pt.astype(jnp.bfloat16), dob, ((1,), (0,)))
+        dst = pt * (_dot(vb, dob, ((1,), (1,))) - delta)
+        dk = _dot(dst.astype(jnp.bfloat16), qb, ((1,), (0,)))
         return dk, dv
 
-    body = compute
+    def full_body(i, carry):
+        dk, dv = grad(kb, vb, i * block_q, block_q)
+        return carry[0] + dk, carry[1] + dv
 
-    # dynamic LOWER bound: q tiles wholly above the diagonal contribute
-    # nothing to this kv tile's dk/dv
+    def masked_body(i, carry):
+        dk, dv = grad(kb, vb, i * block_q, block_q,
+                      i * block_q - ki * block_k)
+        return carry[0] + dk, carry[1] + dv
+
+    # q tiles [lo, lo_full) touch the diagonal and run masked, then
+    # [lo_full, n_q) lie wholly below it; q tiles above the diagonal
+    # contribute nothing to this kv tile's dk/dv
     lo_tri = (ki * block_k) // block_q
-    lo = jnp.where(kfull, 0,
-                   jnp.where(ktri, lo_tri, n_q)).astype(jnp.int32)
-    dk0 = jnp.zeros((block_k, D), jnp.float32)
-    dv0 = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = lax.fori_loop(lo, n_q, body, (dk0, dv0))
-    dk_ref[0] = dk * sm_scale
-    dv_ref[0] = dv
+    lo_full = ((ki + 1) * block_k + block_q - 2) // block_q
+    lo = jnp.where(kfull, 0, jnp.where(ktri, lo_tri, n_q))
+    lo_full = jnp.where(kfull, 0,
+                        jnp.where(ktri, jnp.minimum(lo_full, n_q), n_q))
+    lo, lo_full = lo.astype(jnp.int32), lo_full.astype(jnp.int32)
+    zeros = jnp.zeros((block_k, D), jnp.float32)
+    if not sub:
+        dk, dv = lax.fori_loop(lo_full, n_q, full_body,
+                               lax.fori_loop(lo, lo_full, masked_body,
+                                             (zeros, zeros)))
+        dk_ref[0] = dk * sm_scale
+        dv_ref[0] = dv
+        return
+    dk, dv = lax.fori_loop(lo_full, n_q, full_body, (zeros, zeros))
+    diag = ktri & ~kfull
+
+    @pl.when(~diag)
+    def _():
+        dk_ref[0] = dk * sm_scale
+        dv_ref[0] = dv
+
+    @pl.when(diag)
+    def _():
+        # query sub-block r of the diagonal q tile reaches keys
+        # [0, (r+1)*sub) of this tile; only its own sub x sub square
+        # crosses the diagonal
+        n = block_k // sub
+        dks, dvs = [zeros[:sub]] * n, [zeros[:sub]] * n
+        for r in range(n):
+            a, b = grad(kb[:(r + 1) * sub], vb[:(r + 1) * sub],
+                        ki * block_q + r * sub, sub, r * sub, r * sub)
+            for c in range(r + 1):
+                dks[c] = dks[c] + a[c * sub:(c + 1) * sub]
+                dvs[c] = dvs[c] + b[c * sub:(c + 1) * sub]
+        dk_ref[0] = (dk + jnp.concatenate(dks)) * sm_scale
+        dv_ref[0] = dv + jnp.concatenate(dvs)
 
 
 def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
-              block_q: int, block_k: int, interpret: bool):
+              walk: Walk, interpret: bool):
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
+    block_q, block_k = walk.block_q, walk.block_k
     vma = _vma_union(q3, k3, v3, kf, kt, do3, lse, delta)
     if vma:
         q3, k3, v3, kf, kt, do3, lse, delta = (
@@ -284,8 +445,8 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
-                          n_kv=Tk // block_k, sm_scale=sm_scale),
+        functools.partial(_dq_kernel, walk=walk, n_kv=Tk // block_k,
+                          sm_scale=sm_scale),
         grid=(BH, Tq // block_q),
         in_specs=flags + [
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
@@ -301,8 +462,8 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
         name="flash_dq",
     )(kf, kt, q3, k3, v3, do3, lse, delta)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
-                          n_q=Tq // block_q, sm_scale=sm_scale),
+        functools.partial(_dkv_kernel, walk=walk, n_q=Tq // block_q,
+                          sm_scale=sm_scale),
         grid=(BH, Tk // block_k),
         in_specs=flags + [
             pl.BlockSpec((1, Tq, D), lambda bh, ki: (bh, 0, 0)),
@@ -357,11 +518,11 @@ def _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout):
     else:
         B, Tq, H, D = q.shape
         Tk = k.shape[1]
-    bq, bk = _pick_blocks(Tq, Tk, D)
+    walk = causal_walk(Tq, Tk, D)
     q3 = _to3(q, layout)
     k3 = _to3(k, layout)
     v3 = _to3(v, layout)
-    o3, lse8 = _fwd_call(q3, k3, v3, kf, kt, sm_scale, bq, bk, interpret)
+    o3, lse8 = _fwd_call(q3, k3, v3, kf, kt, sm_scale, walk, interpret)
     out = (_from3(o3, B, H, layout), lse8[:, 0, :].reshape(B, H, Tq))
     # the saved output rides in bf16: delta = rowsum(dO·O) tolerates the
     # rounding, and the f32 buffer would otherwise live across the whole
@@ -373,13 +534,13 @@ def _flash_bwd(sm_scale, interpret, layout, res, g):
     q3, k3, v3, kf, kt, o3, lse8, B, H = res
     g_out, g_lse = g
     do3 = _to3(g_out, layout)
-    bq, bk = _pick_blocks(q3.shape[1], k3.shape[1], q3.shape[2])
+    walk = causal_walk(q3.shape[1], k3.shape[1], q3.shape[2])
     # delta rows fold BOTH cotangent sources: rowsum(dO*O) from the output
     # and -g_lse from the ring merge's exp(lse - lse_new) factors
     delta = jnp.sum(do3 * o3, axis=-1) - g_lse.reshape(q3.shape[0], -1)
     delta8 = jnp.broadcast_to(delta[:, None, :], lse8.shape)
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, kf, kt, do3, lse8, delta8,
-                              sm_scale, bq, bk, interpret)
+                              sm_scale, walk, interpret)
     # the flags' zero cotangents keep the flags' own type: in the ring
     # they vary over the sp axis (axis_index), and a plain zeros((1, 1))
     # would not match it under shard_map

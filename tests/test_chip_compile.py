@@ -56,6 +56,29 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _flash_kinds(compiled):
+    """The sorted kinds (fwd, dq, dkv) of the program's kernels, each
+    found both ways the benchmark finds it: by the name its pallas_call
+    gives the instruction (flash_fwd_ms, flash_bwd_ms) and by its
+    signature (flops.flash_kernel, for flash_attn_ms), read from HLO text
+    with operand shapes, as a device trace prints it."""
+    from jax._src.lib import xla_client
+
+    from benchmark import flops
+
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    kinds = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kind = flops.flash_kernel(line.strip())
+            assert kind is not None, line[:200]
+            assert "flash_" + kind[0] in line.split(" = ")[0]
+            kinds.append(kind[0])
+    return sorted(kinds)
+
+
 def _qkv(sharding):
     return [jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16, sharding=sharding)
             for _ in range(3)]
@@ -71,6 +94,7 @@ def _attend(q, k, v):
 def test_flash_forward_compiles(one_chip):
     compiled = jax.jit(_attend).lower(*_qkv(one_chip)).compile()
     assert _n_kernels(compiled) == 1
+    assert _flash_kinds(compiled) == ["fwd"]
 
 
 def test_flash_backward_compiles(one_chip):
@@ -79,6 +103,7 @@ def test_flash_backward_compiles(one_chip):
     compiled = jax.jit(grad).lower(*_qkv(one_chip)).compile()
     # forward + the dq and dk/dv kernels of the backward
     assert _n_kernels(compiled) == 3
+    assert _flash_kinds(compiled) == ["dkv", "dq", "fwd"]
 
 
 def test_train_step_1x2x2_compiles(topo, no_compile_cache, monkeypatch):
@@ -105,3 +130,5 @@ def test_train_step_1x2x2_compiles(topo, no_compile_cache, monkeypatch):
     compiled = step.lower(params, toks, toks).compile()
     # per layer, each of the 2 ring steps runs forward + dq + dk/dv
     assert _n_kernels(compiled) == 2 * 3 * cfg.n_layers
+    assert _flash_kinds(compiled) == sorted(["fwd", "dq", "dkv"] * 2 *
+                                             cfg.n_layers)

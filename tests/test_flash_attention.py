@@ -8,39 +8,50 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ompi_tpu.ops.flash_attention import flash_block, flash_supported
+from ompi_tpu.ops import flash_attention as fa
+from ompi_tpu.ops.flash_attention import (causal_walk, flash_block,
+                                           flash_supported)
 from ompi_tpu.ops.ring_attention import reference_attention
 
-B, T, H, D = 2, 64, 2, 16
+# (B, T, H, D): a small geometry whose 64-wide diagonal tile is masked
+# whole, and the flagship's per-head geometry, where 512-wide tiles
+# split their diagonal into sub-blocks
+GEOMS = {"t64_d16": (2, 64, 2, 16), "t1024_d128": (1, 1024, 2, 128)}
+# (keep_full, keep_tri) of the ring's three block relations
+MODES = {"tri": (0.0, 1.0), "full": (1.0, 0.0), "none": (0.0, 0.0)}
 
 
-@pytest.fixture(scope="module")
-def qkv():
+@pytest.fixture(scope="module", params=list(GEOMS))
+def qkv(request):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    return tuple(jax.random.normal(k, (B, T, H, D), jnp.float32)
+    return tuple(jax.random.normal(k, GEOMS[request.param], jnp.float32)
                  for k in ks)
 
 
-def test_flash_causal_matches_dense(qkv):
+def _dense(q, k, v, mode):
+    """(out, lse) of the block relation ``mode`` by the dense reference;
+    an empty block gives zeros and the -1e30 sentinel."""
+    B, T, H, D = q.shape
+    if mode == "none":
+        return jnp.zeros_like(q), jnp.full((B, H, T), -1e30)
+    causal = mode == "tri"
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    if causal:
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        s = jnp.where(mask[None, None], s, -jnp.inf)
+    return (reference_attention(q, k, v, causal=causal),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_flash_forward_matches_dense(qkv, mode):
     q, k, v = qkv
-    out, lse = flash_block(q, k, v, 0.0, 1.0, interpret=True)
-    ref = reference_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
-    assert lse.shape == (B, H, T)
-
-
-def test_flash_full_matches_dense(qkv):
-    q, k, v = qkv
-    out, _ = flash_block(q, k, v, 1.0, 0.0, interpret=True)
-    ref = reference_attention(q, k, v, causal=False)
-    np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
-
-
-def test_flash_none_block_is_empty(qkv):
-    q, k, v = qkv
-    out, lse = flash_block(q, k, v, 0.0, 0.0, interpret=True)
-    assert bool(jnp.all(out == 0.0))
-    assert bool(jnp.all(lse <= -1e29))  # empty sentinel
+    out, lse = flash_block(q, k, v, *MODES[mode], interpret=True)
+    ref_out, ref_lse = _dense(q, k, v, mode)
+    assert lse.shape == ref_lse.shape
+    tol = 0.0 if mode == "none" else 2e-2  # an empty block is exact
+    np.testing.assert_allclose(out, ref_out, atol=tol, rtol=tol)
+    np.testing.assert_allclose(lse, ref_lse, atol=tol, rtol=tol)
 
 
 def test_flash_bhtd_layout_matches(qkv):
@@ -55,21 +66,18 @@ def test_flash_bhtd_layout_matches(qkv):
                                atol=1e-5)
 
 
-def test_flash_grads_match_dense(qkv):
+@pytest.mark.parametrize("mode", list(MODES))
+def test_flash_grads_match_dense(qkv, mode):
     """dq/dk/dv (incl. the lse cotangent path the ring merge exercises)
     against autodiff through the dense reference."""
     q, k, v = qkv
 
     def floss(q_, k_, v_):
-        o, l = flash_block(q_, k_, v_, 0.0, 1.0, interpret=True)
+        o, l = flash_block(q_, k_, v_, *MODES[mode], interpret=True)
         return jnp.sum(o * o) + jnp.sum(jnp.tanh(l / 10.0))
 
     def rloss(q_, k_, v_):
-        o = reference_attention(q_, k_, v_, causal=True)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) / np.sqrt(D)
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-        l = jax.nn.logsumexp(s, axis=-1)
+        o, l = _dense(q_, k_, v_, mode)
         return jnp.sum(o * o) + jnp.sum(jnp.tanh(l / 10.0))
 
     gf = jax.grad(floss, argnums=(0, 1, 2))(q, k, v)
@@ -102,3 +110,38 @@ def test_flash_supported_gate():
                            layout="bhtd")
     # K/V VMEM budget: enormous per-device KV must fall back
     assert not flash_supported((1, 256, 1, 128), (1, 1 << 20, 1, 128))
+
+
+@pytest.mark.parametrize("t,sub,visited", [
+    (1024, 256, 655_360), (4096, 256, 8_912_896),
+    (1024, 128, 589_824), (4096, 128, 8_650_752)])
+def test_causal_walk_stops_at_the_diagonal(monkeypatch, t, sub, visited):
+    """512-wide tiles, the diagonal's in sub-blocks (256, the kernels'
+    choice, and 128, set here): the walk lies between the triangle and
+    the whole tiles that touch it."""
+    monkeypatch.setattr(fa, "_DIAG_SUB", sub)
+    w = causal_walk(t, t, 128)
+    n = t // 512
+    assert (w.block_q, w.block_k, w.sub) == (512, 512, sub)
+    whole = n * (n + 1) // 2 * 512 * 512     # 786,432 at T=1024
+    triangle = t * (t + 1) // 2              # 524,800 at T=1024
+    assert triangle < w.visited == visited < whole
+    # only each sub-block's own square on the diagonal is masked
+    assert w.masked == n * (512 // sub) * sub * sub
+
+
+@pytest.mark.parametrize("tq,tk,d", [(64, 64, 16), (32, 16, 16),
+                                     (1024, 512, 128)])
+def test_causal_walk_masks_whole_tiles_off_the_split(tq, tk, d):
+    """No split where the diagonal tile is not a square of whole
+    sub-blocks: every tile touching the triangle is masked whole."""
+    w = causal_walk(tq, tk, d)
+    assert w.sub == 0
+    touching = sum(min((qi * w.block_q + w.block_q + w.block_k - 1)
+                       // w.block_k, tk // w.block_k)
+                   for qi in range(tq // w.block_q))
+    below = sum(min((qi * w.block_q + 1) // w.block_k, tk // w.block_k)
+                for qi in range(tq // w.block_q))
+    tile = w.block_q * w.block_k
+    assert w.visited == touching * tile
+    assert w.masked == (touching - below) * tile
