@@ -628,7 +628,8 @@ class _Conn:
                  "rel", "rel_rx", "state", "tx_seq", "tx_acked",
                  "tx_released", "retx", "retx_bytes", "rx_floor",
                  "rx_seen", "unacked_n", "unacked_b", "last_ack_tx",
-                 "retx_strikes", "last_retx_t", "degraded_at",
+                 "retx_strikes", "last_retx_t", "retx_hole",
+                 "degraded_at",
                  "redial_deadline", "redial_n", "reconnects",
                  "crc_errs", "last_crc", "esc_eof",
                  # link telemetry (runtime/linkmodel.py + adaptive retx)
@@ -713,6 +714,7 @@ class _Conn:
         self.last_ack_tx = 0.0
         self.retx_strikes = 0  # consecutive retx timeouts w/o ack progress
         self.last_retx_t = 0.0  # NACK-retransmit rate limit clock
+        self.retx_hole = 0     # oldest seq the last retransmit resent
         self.degraded_at = 0.0
         self.redial_deadline = 0.0
         self.redial_n = 0      # attempts in the CURRENT outage
@@ -1478,20 +1480,25 @@ class TcpBtl(Btl):
                 and _linkmodel._enable_var._value:
             _linkmodel.note_rtt_sample(conn.peer, sample)
 
-    def _rel_retransmit(self, conn: _Conn) -> None:
+    def _rel_retransmit(self, conn: _Conn, floor: int) -> None:
         """NACK service: retransmit every retained frame in seq order
         (sender-side go-back-N — the receiver's dedup makes overlap
         free and the window bound keeps the tail small). Rate-limited:
         a burst of NACKs from one corruption storm must not multiply
-        the resend."""
+        the resend. The storm's NACKs all carry the floor below the
+        hole the last resend covered; a NACK whose ``floor`` reaches
+        that hole proves it was filled, so it names a NEW loss and is
+        served at once (else it would wait out the retransmit timer
+        and never count as NACK-evidenced loss)."""
         now = time.monotonic()
         with conn.wlock:
             if conn.dead is not None or conn.state != "est" \
                     or not conn.retx:
                 return
-            if now - conn.last_retx_t < 0.02:
+            if now - conn.last_retx_t < 0.02 and floor < conn.retx_hole:
                 return  # this storm already triggered a resend
             conn.last_retx_t = now
+            conn.retx_hole = next(iter(conn.retx))
             for seq in list(conn.retx):
                 if conn.dead is not None or conn.state != "est":
                     break  # a transmit failure degraded us mid-loop
@@ -1525,7 +1532,7 @@ class TcpBtl(Btl):
             self._rel_ack_rx(conn, a)
         elif typ == _CTL_NACK:
             self._rel_ack_rx(conn, a)  # the floor is a cumulative ack
-            self._rel_retransmit(conn)
+            self._rel_retransmit(conn, a)
         elif typ == _CTL_RESYNC:
             self._rel_resync_rx(conn, a, b)
 
@@ -1974,6 +1981,7 @@ class TcpBtl(Btl):
                     continue
                 rnow = time.monotonic()
                 conn.last_retx_t = rnow
+                conn.retx_hole = next(iter(conn.retx))
                 for seq in list(conn.retx):
                     if conn.dead is not None or conn.state != "est":
                         break  # transmit failure degraded us mid-loop

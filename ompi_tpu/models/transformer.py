@@ -226,8 +226,8 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
 def forward_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
                   in_mesh: bool = False, causal_ring: bool = True):
     """Forward to logits [B, T, vocab] (dense — for inference/tests; the
-    training loss streams the vocab projection instead, see
-    ops/softmax_xent.py)."""
+    training loss streams the vocab projection in chunks instead and
+    forms its gradient in the same pass, see ops/softmax_xent.py)."""
     from ompi_tpu.ops.softmax_xent import logits_matmul
 
     x = features_local(params, tokens, cfg, tp=tp, sp=sp, in_mesh=in_mesh,

@@ -6,11 +6,15 @@ alive across the whole backward — at the HBM ceiling XLA starts spilling
 and the measured cost was ~64 ms/step plus the memory pressure that
 slowed attention down (r4 ablation, tools/profile_mfu.py).
 
-This op streams the vocabulary projection in sequence chunks with an
-explicit recompute-in-backward (custom_vjp): forward keeps only the
-per-row logsumexp ([B, T] f32); backward re-scores each chunk and feeds
-the (softmax - onehot) rows straight into the dx / dW matmuls. Peak
-live logits memory drops from O(B·T·V) to O(B·Tc·V).
+This op streams the vocabulary projection in sequence chunks. Without
+AD the scan scores each chunk and keeps only the loss. Under AD the
+custom_vjp's forward rule computes the gradient in the same scan: the
+loss is a scalar, so its cotangent only scales the result, and each
+chunk's (softmax - onehot) rows feed the dx / dW matmuls while its
+logits are live. Three vocabulary matmuls a chunk, none recomputed; the
+backward scales the saved f32 (dx, dW) by the cotangent. Peak live
+logits memory drops from O(B·T·V) to O(B·Tc·V) (fused linear
+cross-entropy, as Liger-Kernel's).
 
 Reference analog: the segmented-pipeline discipline of
 ompi/mca/coll/base/coll_base_allreduce.c:622 (never hold the whole
@@ -35,6 +39,38 @@ def _chunk_count(T: int, chunk_t: int) -> int:
     return max(c, 1)
 
 
+def _chunks(x, targets, chunk_t: int):
+    """x [B, T, D] and targets [B, T] as [nc, B, Tc, ...] scan operands."""
+    B, T, D = x.shape
+    Tc = _chunk_count(T, chunk_t)
+    nc = T // Tc
+    return (x.reshape(B, nc, Tc, D).transpose(1, 0, 2, 3),
+            targets.reshape(B, nc, Tc).transpose(1, 0, 2))
+
+
+def _chunk_loss(xb, w, tb):
+    """One chunk's logits [B, Tc, V] f32, per-row logsumexp and summed
+    loss term."""
+    logits = logits_matmul(xb, w)
+    m = jnp.max(logits, axis=-1)
+    lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
+    # gold logit via the gathered embedding ROW (a [B,Tc,D] gather +
+    # rowwise dot), not take_along_axis over the [B,Tc,V] logits —
+    # one fewer full pass over the chunk's largest tensor. Matmul
+    # in the same bf16/f32-accum regime as logits_matmul so the
+    # values agree bit-for-bit in spirit (tested to bf16 tolerance).
+    wrows = w[tb].astype(jnp.bfloat16)  # [B, Tc, D]
+    gold = jnp.einsum("btd,btd->bt", xb.astype(jnp.bfloat16), wrows,
+                      preferred_element_type=jnp.float32)
+    return logits, lse, jnp.sum(lse - gold)
+
+
+def _vzero(x):
+    # inside shard_map a scan carry must carry the body's varying
+    # mesh-axes type (it depends on x), which a plain zeros literal lacks
+    return x.reshape(-1)[0].astype(jnp.float32) * 0.0
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def softmax_xent_sum(x, w, targets, chunk_t: int = 128,
                      psum_axes: tuple = ()):
@@ -51,8 +87,13 @@ def softmax_xent_sum(x, w, targets, chunk_t: int = 128,
     must be explicitly summed across the shards that saw different
     (b, t) cells. Omitting it outside shard_map is fine.
     """
-    loss, _ = _xent_fwd(x, w, targets, chunk_t, psum_axes)
-    return loss
+    def body(tot, args):
+        xb, tb = args
+        return tot + _chunk_loss(xb, w, tb)[2], None
+
+    total, _ = lax.scan(body, jnp.zeros((), jnp.float32) + _vzero(x),
+                        _chunks(x, targets, chunk_t))
+    return total
 
 
 def logits_matmul(xc, w):
@@ -66,58 +107,33 @@ def logits_matmul(xc, w):
 
 def _xent_fwd(x, w, targets, chunk_t: int, psum_axes: tuple = ()):
     B, T, D = x.shape
-    Tc = _chunk_count(T, chunk_t)
-    nc = T // Tc
-    xc = x.reshape(B, nc, Tc, D).transpose(1, 0, 2, 3)
-    tc = targets.reshape(B, nc, Tc).transpose(1, 0, 2)
+    wb = w.astype(jnp.bfloat16)
 
-    def body(tot, args):
+    def body(carry, args):
+        tot, dw = carry
         xb, tb = args
-        logits = logits_matmul(xb, w)  # [B, Tc, V]
-        m = jnp.max(logits, axis=-1)
-        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
-        # gold logit via the gathered embedding ROW (a [B,Tc,D] gather +
-        # rowwise dot), not take_along_axis over the [B,Tc,V] logits —
-        # one fewer full pass over the chunk's largest tensor. Matmul
-        # in the same bf16/f32-accum regime as logits_matmul so the
-        # values agree bit-for-bit in spirit (tested to bf16 tolerance).
-        wrows = w[tb].astype(jnp.bfloat16)  # [B, Tc, D]
-        gold = jnp.einsum("btd,btd->bt", xb.astype(jnp.bfloat16), wrows,
-                          preferred_element_type=jnp.float32)
-        return tot + jnp.sum(lse - gold), lse
-
-    vzero = x.reshape(-1)[0].astype(jnp.float32) * 0.0
-    total, lses = lax.scan(body, jnp.zeros((), jnp.float32) + vzero,
-                           (xc, tc))
-    return total, (x, w, targets, lses)
-
-
-def _xent_bwd(chunk_t: int, psum_axes: tuple, res, g):
-    x, w, targets, lses = res
-    B, T, D = x.shape
-    Tc = _chunk_count(T, chunk_t)
-    nc = T // Tc
-    xc = x.reshape(B, nc, Tc, D).transpose(1, 0, 2, 3)
-    tc = targets.reshape(B, nc, Tc).transpose(1, 0, 2)
-
-    def body(dw, args):
-        xb, tb, lse = args
-        logits = logits_matmul(xb, w)
+        logits, lse, loss = _chunk_loss(xb, w, tb)
         p = jnp.exp(logits - lse[..., None])  # softmax rows
         onehot = jax.nn.one_hot(tb, w.shape[0], dtype=p.dtype)
         d = (p - onehot).astype(jnp.bfloat16)  # [B, Tc, V]
-        dx = jnp.einsum("btv,vd->btd", d, w.astype(jnp.bfloat16),
+        dx = jnp.einsum("btv,vd->btd", d, wb,
                         preferred_element_type=jnp.float32)
         dw = dw + jnp.einsum("btv,btd->vd", d, xb.astype(jnp.bfloat16),
                              preferred_element_type=jnp.float32)
-        return dw, dx
+        return (tot + loss, dw), dx
 
-    # vzero: inside shard_map the carry must carry the body's varying
-    # mesh-axes type (it depends on x), which a plain zeros literal lacks
-    vzero = x.reshape(-1)[0].astype(jnp.float32) * 0.0
-    dw, dxc = lax.scan(body, jnp.zeros(w.shape, jnp.float32) + vzero,
-                       (xc, tc, lses))
+    vzero = _vzero(x)
+    (total, dw), dxc = lax.scan(
+        body, (jnp.zeros((), jnp.float32) + vzero,
+               jnp.zeros(w.shape, jnp.float32) + vzero),
+        _chunks(x, targets, chunk_t))
     dx = dxc.transpose(1, 0, 2, 3).reshape(B, T, D)
+    # the 0-d zeros carry the primal dtypes to the cotangents' casts
+    return total, (dx, dw, jnp.zeros((), x.dtype), jnp.zeros((), w.dtype))
+
+
+def _xent_bwd(chunk_t: int, psum_axes: tuple, res, g):
+    dx, dw, x0, w0 = res
     # w is replicated over the data axes x varies on (shard_map vma): its
     # cotangent must be the cross-shard SUM — the psum AD auto-inserts for
     # plain einsums, made explicit here because custom_vjp is opaque to it
@@ -126,8 +142,8 @@ def _xent_bwd(chunk_t: int, psum_axes: tuple, res, g):
     dx = gf * dx  # result's vma matches the replicated primal
     if psum_axes:
         dw = lax.psum(dw, tuple(psum_axes))
-    return (dx.astype(x.dtype), dw.astype(w.dtype),
-            np.zeros(targets.shape, dtype=jax.dtypes.float0))
+    return (dx.astype(x0.dtype), dw.astype(w0.dtype),
+            np.zeros(dx.shape[:2], dtype=jax.dtypes.float0))
 
 
 softmax_xent_sum.defvjp(_xent_fwd, _xent_bwd)

@@ -106,18 +106,13 @@ def test_flash_backward_compiles(one_chip):
     assert _flash_kinds(compiled) == ["dkv", "dq", "fwd"]
 
 
-def test_train_step_1x2x2_compiles(topo, no_compile_cache, monkeypatch):
-    """The four-chip dp x sp x tp = 1x2x2 step at full width, two layers:
-    ring attention's flash backward must give the sp-varying keep flags
-    cotangents of their own type."""
+def _compile_step(topo, devices, batch):
+    """The flagship step at full width, two layers, compiled for a
+    (dp, sp, tp) mesh of the described chips."""
     from ompi_tpu.models import transformer as tfm
-    from ompi_tpu.ops import ring_attention
 
-    # the backend here is the CPU, so the default would pick the lax path
-    monkeypatch.setattr(ring_attention, "use_flash_default",
-                        lambda *a, **k: True)
     cfg = dataclasses.replace(tfm.FLAGSHIP, n_layers=2)
-    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2), ("dp", "sp", "tp"))
+    mesh = Mesh(np.array(devices), ("dp", "sp", "tp"))
     step, _ = tfm.make_train_step(mesh, cfg)
     params = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
                             jax.random.PRNGKey(0))
@@ -125,10 +120,46 @@ def test_train_step_1x2x2_compiles(topo, no_compile_cache, monkeypatch):
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                           sharding=NamedSharding(mesh, s)),
         params, tfm.param_specs(cfg))
-    toks = jax.ShapeDtypeStruct((8, cfg.seq_len), jnp.int32,
+    toks = jax.ShapeDtypeStruct((batch, cfg.seq_len), jnp.int32,
                                 sharding=NamedSharding(mesh, P("dp", "sp")))
-    compiled = step.lower(params, toks, toks).compile()
+    return cfg, step.lower(params, toks, toks).compile()
+
+
+@pytest.fixture
+def flash_on(monkeypatch):
+    from ompi_tpu.ops import ring_attention
+
+    # the backend here is the CPU, so the default would pick the lax path
+    monkeypatch.setattr(ring_attention, "use_flash_default",
+                        lambda *a, **k: True)
+
+
+def test_train_step_1x2x2_compiles(topo, no_compile_cache, flash_on):
+    """The four-chip dp x sp x tp = 1x2x2 step at full width, two layers:
+    ring attention's flash backward must give the sp-varying keep flags
+    cotangents of their own type."""
+    cfg, compiled = _compile_step(
+        topo, np.array(topo.devices).reshape(1, 2, 2), 8)
     # per layer, each of the 2 ring steps runs forward + dq + dk/dv
     assert _n_kernels(compiled) == 2 * 3 * cfg.n_layers
     assert _flash_kinds(compiled) == sorted(["fwd", "dq", "dkv"] * 2 *
                                              cfg.n_layers)
+
+
+def test_train_step_one_chip_compiles(topo, no_compile_cache, flash_on):
+    """The one-chip flagship step (batch 36): the loss scan scores each
+    chunk once and feeds the same logits to the dx and dW matmuls, so the
+    program holds three vocabulary-wide convolutions, not four, and its
+    temporaries fit the chip's 16 GB."""
+    from jax._src.lib import xla_client
+
+    cfg, compiled = _compile_step(
+        topo, np.array(topo.devices[:1]).reshape(1, 1, 1), 36)
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    vocab = [ln for ln in text.splitlines()
+             if " convolution(" in ln and str(cfg.vocab) in ln]
+    assert len(vocab) == 3, vocab
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30

@@ -306,6 +306,36 @@ def test_slow_link_no_spurious_strikes(link_knobs):
         b.finalize()
 
 
+def test_nack_rate_limit_holds_storm_serves_new_hole(link_knobs):
+    """Within the NACK rate limit, a repeat NACK for the hole just
+    resent is the same storm and resends nothing; a NACK whose floor
+    shows that hole filled names a new loss and is served at once."""
+    set_var("btl_tcp", "reliable", 1)
+    got_a, got_b = [], []
+    a, b, conn = _established(got_a, got_b)
+    sent = []
+    real_transmit = b._rel_transmit
+    try:
+        seqs = _fabricate(conn, ages=[0.0, 0.0, 0.0, 0.0])
+        b._rel_transmit = lambda c, vecs, cls: sent.append(cls)
+        floor = seqs[0] - 1  # the receiver lost the oldest frame
+        b._rel_retransmit(conn, floor)
+        assert len(sent) == 4 and conn.nack_retx_n == 4
+        b._rel_retransmit(conn, floor)  # same storm: held
+        assert len(sent) == 4 and conn.nack_retx_n == 4
+        # the hole filled, a later frame was lost: resend the rest now
+        b._rel_ack_rx(conn, seqs[1])
+        b._rel_retransmit(conn, seqs[1])
+        assert len(sent) == 6 and conn.nack_retx_n == 6
+    finally:
+        b._rel_transmit = real_transmit
+        with conn.wlock:
+            conn.retx.clear()  # fabricated frames must not outlive us
+            conn.retx_bytes = 0
+        a.finalize()
+        b.finalize()
+
+
 # ------------------------------------------------------------ active probe
 class _FakePml:
     my_rank = 0

@@ -38,16 +38,60 @@ def test_odd_t_falls_back_to_divisor_chunk():
     assert abs(ours - ref) < 1e-2 * max(abs(ref), 1.0)
 
 
-def test_grads_match_reference():
-    x, w, t = _data()
-    gx, gw = jax.grad(lambda a, b: softmax_xent_sum(a, b, t, 16),
-                      argnums=(0, 1))(x, w)
+# (T, chunk_t): the chunk sizes, and 48 % 32 != 0 (chunk shrinks to 16)
+GRAD_CASES = [(64, 16), (64, 64), (64, 128), (48, 32)]
+
+
+@pytest.mark.parametrize("T,chunk_t", GRAD_CASES)
+def test_grads_match_reference(T, chunk_t):
+    """The fused rule's (loss, dx, dw) from value_and_grad against the
+    dense reference, and its loss against the primal call's."""
+    x, w, t = _data(T=T)
+    loss, (gx, gw) = jax.value_and_grad(
+        lambda a, b: softmax_xent_sum(a, b, t, chunk_t), argnums=(0, 1))(x, w)
     rx, rw = jax.grad(lambda a, b: reference_xent_sum(a, b, t),
                       argnums=(0, 1))(x, w)
+    ref = float(_bf16_ref(x, w, t))
+    assert abs(float(loss) - ref) < 1e-2 * max(abs(ref), 1.0)
+    np.testing.assert_allclose(float(loss),
+                               float(softmax_xent_sum(x, w, t, chunk_t)),
+                               rtol=1e-6)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
                                atol=6e-2, rtol=6e-2)
     np.testing.assert_allclose(np.asarray(gw), np.asarray(rw),
                                atol=6e-2, rtol=6e-2)
+
+
+def _vocab_dots(jaxpr, V):
+    """dot_generals in a jaxpr and its sub-jaxprs with an operand or a
+    result of a vocabulary-sized dimension."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+            n += any(V in s for s in shapes)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, ClosedJaxpr):
+                    n += _vocab_dots(sub.jaxpr, V)
+                elif isinstance(sub, Jaxpr):
+                    n += _vocab_dots(sub, V)
+    return n
+
+
+def test_vocab_matmuls_per_chunk():
+    """Under AD one scan scores each chunk and runs the dx and dW
+    matmuls on the same logits: three vocabulary matmuls, none
+    recomputed. The primal call without AD runs the scoring one only."""
+    V = 101
+    x, w, t = _data(V=V)
+    vg = jax.make_jaxpr(jax.value_and_grad(
+        lambda a, b: softmax_xent_sum(a, b, t, 16), argnums=(0, 1)))(x, w)
+    assert _vocab_dots(vg.jaxpr, V) == 3
+    primal = jax.make_jaxpr(lambda a, b: softmax_xent_sum(a, b, t, 16))(x, w)
+    assert _vocab_dots(primal.jaxpr, V) == 1
 
 
 def test_sharded_grad_matches_single():
