@@ -23,7 +23,7 @@ hot-guard                 in hot modules (parallel/mesh.py, pml/ob1.py,
                           guard: ``X.enabled()`` / ``X._enable_var._value`` (or
                           a local name assigned from one) — context-manager
                           construction on the disabled path is too expensive
-                          (bench.py prologue_us discipline).
+                          for a per-call prologue.
 span-ctx                  ``trace.span(...)`` must be entered through ``with``
                           (or an assigned name used in a ``with``, or inside a
                           try/finally) — a span that never exits corrupts B/E

@@ -23,9 +23,7 @@ a pooled block per connection and hands the pml *slices* of it; a copy
 happens only at the pml delivery boundary when a payload must outlive
 the block (unexpected-queue stash, system-plane blobs). The remaining
 copies are measured, not estimated: ``btl_tcp_bytes_copied`` /
-``btl_tcp_writev_calls`` / ``btl_tcp_wire_bytes`` pvars, and
-``btl_tcp_copy_mode=1`` re-materializes the legacy copies so bench can
-A/B the tax in one process.
+``btl_tcp_writev_calls`` / ``btl_tcp_wire_bytes`` pvars.
 
 Priority-aware traffic shaping (``btl_tcp_shape_enable``): each
 connection's send backlog becomes three QoS-class sub-queues
@@ -158,14 +156,6 @@ _vecs_var = register_var(
     help="Max iovecs handed to one sendmsg() when draining the "
          "vectored write queue (IOV_MAX guard; reference: the btl "
          "writev scatter-gather of opal's tcp frag lists)", level=5)
-_copy_mode_var = register_var(
-    "btl_tcp", "copy_mode", 0,
-    help="1 = legacy copying datapath: materialize the eager-payload "
-         "copy, the frame concat, the per-recv 1 MiB allocation + "
-         "rbuf concat, and the receive parse copies the zero-copy "
-         "vectored path eliminates. A/B baseline for bench.py's p2p "
-         "section — the copies feed btl_tcp_bytes_copied either way, "
-         "so copies-per-wire-byte is measured, not estimated", level=9)
 
 # ------------------------------------------------- priority traffic shaping
 # btl_tcp_shape_enable / shape_segment_bytes live in ompi_tpu/qos.py
@@ -215,9 +205,7 @@ _reliable_var = register_var(
          "handshake — both sides must advertise; a reliable=0 peer "
          "interops at plain framing. 0 = legacy wire format, "
          "bit-identical to the pre-reliability build (the A/B "
-         "baseline; btl_tcp_copy_mode=1 bench runs should also set 0 — "
-         "legacy-datapath frames bypass the envelope and are not "
-         "retained). With reliability on, one frame tops out at "
+         "baseline). With reliability on, one frame tops out at "
          "512 MiB instead of 2 GiB: length-word bits 29/30 become the "
          "envelope/control flags (see the framing guard in send())",
     level=4)
@@ -372,7 +360,7 @@ register_pvar("btl_tcp", "bytes_copied",
               lambda: _ctr["copied"],
               help="Payload/frame bytes the tcp datapath had to copy "
                    "(write-queue ownership under backpressure, rx "
-                   "compaction/grow, legacy copy_mode re-adds)")
+                   "compaction/grow)")
 register_pvar("btl_tcp", "writev_calls",
               lambda: _ctr["writev"],
               help="Vectored sendmsg() syscalls issued by the write "
@@ -534,7 +522,7 @@ _LEN_MASK = _ZFLAG - 1
 # whose handshake engaged reliability (rel_rx): bit 30 marks a
 # link-control frame, bit 29 a reliability-enveloped data frame. A
 # legacy (unflagged) frame stays parseable mid-stream — the
-# copy_mode=1 datapath and the connector's pre-ack traffic ride it.
+# connector's pre-ack traffic rides it.
 _LFLAG = 1 << 30
 _RFLAG = 1 << 29
 # reliable builds cap EVERY outbound frame here (512 MiB) so a legacy
@@ -620,7 +608,7 @@ def _corrupt_wire_copy(vecs: List) -> List:
 
 
 class _Conn:
-    __slots__ = ("sock", "rxb", "rstart", "rend", "wq", "wbuf", "rbuf",
+    __slots__ = ("sock", "rxb", "rstart", "rend", "wq",
                  "wlock", "peer", "dead", "peer_z", "await_ack",
                  "wqs", "cur", "cur_cls", "deficit", "defer", "peer_q",
                  "eseq", "last_rx", "last_tx",
@@ -638,10 +626,6 @@ class _Conn:
 
     def __init__(self, sock: socket.socket, peer: Optional[int] = None):
         self.sock = sock
-        # legacy concat queues, used ONLY under btl_tcp_copy_mode=1
-        # (the bench A/B baseline) — empty otherwise
-        self.wbuf = bytearray()
-        self.rbuf = bytearray()
         # receive staging: a pooled block filled by recv_into, with the
         # unparsed span at [rstart, rend). Acquired lazily on first
         # drain, returned to the pool when the conn unregisters.
@@ -834,7 +818,6 @@ class TcpBtl(Btl):
                     "dead_reason": str(conn.dead) if conn.dead else None,
                     "wq_frames": len(conn.wq),
                     "wq_bytes": sum(len(b) for b in conn.wq),
-                    "legacy_wbuf_bytes": len(conn.wbuf),
                     "rx_partial_bytes": max(0, r1 - r0),
                     "last_rx_age_s": None if conn.last_rx is None
                     else round(now - conn.last_rx, 3),
@@ -1139,14 +1122,6 @@ class TcpBtl(Btl):
             # past the check into a cleared queue
             if conn.dead is not None:
                 self._raise_dead(conn)
-            if _copy_mode_var._value:
-                # legacy A/B datapath: bypasses the reliability
-                # envelope by design — per-frame flags keep an engaged
-                # peer's parser happy, but these frames are NOT
-                # retained (the reliable cvar help tells copy_mode
-                # bench runs to set reliable=0)
-                self._send_legacy(conn, lenw, header, mv, dup)
-                return
             if conn.rel:
                 cls = header[0] >> QOS_SHIFT
                 txv = self._rel_envelope(conn, header, mv, nbytes,
@@ -1175,11 +1150,6 @@ class TcpBtl(Btl):
                     # shaped residue after a shape_enable flip: older
                     # frames must hit the wire first
                     self._fold_shaped_residue(conn)
-                if conn.wbuf:
-                    # legacy residue after a copy_mode flip: older
-                    # frames must hit the wire first
-                    conn.wq.append(bytes(conn.wbuf))
-                    conn.wbuf.clear()
                 backlog = bool(conn.wq)
                 if not backlog:
                     # fast path: push straight from the caller's buffer
@@ -1205,65 +1175,6 @@ class TcpBtl(Btl):
         from ompi_tpu.runtime import progress as _progress
 
         _progress.poke()
-
-    def _fold_wq_legacy(self, conn: _Conn) -> None:
-        """Vectored residue after a copy_mode flip: fold the deque into
-        the legacy concat queue, oldest first. Caller holds wlock."""
-        while conn.wq:
-            conn.wbuf += conn.wq.popleft()  # mpilint: disable=hot-copy — mode-flip bridge into the legacy A/B queue
-
-    def _send_legacy(self, conn: _Conn, lenw: bytes, header: bytes,
-                     mv, dup: bool) -> None:
-        """The pre-vectored datapath, verbatim (btl_tcp_copy_mode=1,
-        the bench A/B baseline): unconditional eager-payload copy,
-        frame concat, bytes-concat queue append, byte-wise flush. The
-        copies feed btl_tcp_bytes_copied so copies-per-wire-byte is
-        MEASURED on the real legacy code, not modeled. Caller holds
-        conn.wlock and has done the dead-check."""
-        if conn.cur is not None or \
-                (conn.wqs is not None and any(conn.wqs)):
-            # shaped residue after a copy_mode flip: a partially-written
-            # shaped frame MUST finish (and older shaped frames must
-            # drain) before legacy bytes hit the wire, or the stream
-            # desyncs / same-class frames overtake their seqs
-            self._fold_shaped_residue(conn)
-        payload = bytes(mv)  # the old eager copy (pre-PR tcp.py:277)  # mpilint: disable=hot-copy — legacy A/B path reproduces the old copies on purpose
-        frame = lenw + header + payload
-        _ctr["copied"] += len(payload) + len(frame)
-        self._fold_wq_legacy(conn)
-        conn.wbuf += frame  # mpilint: disable=hot-copy — legacy A/B path reproduces the old concat queue on purpose
-        _ctr["copied"] += len(frame)
-        if dup:
-            conn.wbuf += frame  # mpilint: disable=hot-copy — legacy A/B path
-            _ctr["copied"] += len(frame)
-        self._flush_legacy(conn)
-
-    def _flush_legacy(self, conn: _Conn) -> None:
-        """The pre-vectored flush: byte-wise send + O(n) front-trim of
-        the concat queue (O(n^2) across a backlog — the measured tax).
-        Caller holds conn.wlock."""
-        if conn.cur is not None or \
-                (conn.wqs is not None and any(conn.wqs)):
-            # shaped residue after a copy_mode flip: ordered first
-            self._fold_shaped_residue(conn)
-        self._fold_wq_legacy(conn)
-        while conn.wbuf:
-            try:
-                sent = conn.sock.send(conn.wbuf)
-            except socket.error as e:
-                if e.errno in (errno.EAGAIN, errno.EWOULDBLOCK):
-                    self._want_write(conn, True)
-                    return
-                self._conn_failed(conn, e)
-                return
-            if sent <= 0:
-                self._want_write(conn, True)
-                return
-            _ctr["wire"] += sent
-            if _forensics._enable_var._value:  # last-tx dump evidence
-                conn.last_tx = time.monotonic()
-            del conn.wbuf[:sent]
-        self._want_write(conn, False)
 
     def _try_send(self, conn: _Conn, vecs: List) -> List:
         """Vectored push of ``vecs`` until the socket blocks; returns
@@ -1397,9 +1308,6 @@ class TcpBtl(Btl):
                 (conn.wqs is not None and any(conn.wqs)):
             # shaped residue after a shape_enable flip: ordered first
             self._fold_shaped_residue(conn)
-        if conn.wbuf:
-            conn.wq.append(bytes(conn.wbuf))
-            conn.wbuf.clear()
         backlog = bool(conn.wq)
         if not backlog:
             vecs = self._try_send(conn, vecs)
@@ -1586,7 +1494,6 @@ class TcpBtl(Btl):
                 # queued wire copies raced the old socket and are
                 # stale; every frame that matters is in retx
                 conn.wq.clear()
-                conn.wbuf.clear()
                 self._drop_shaped(conn)
                 now = time.monotonic()
                 replayed = len(conn.retx)
@@ -1650,7 +1557,6 @@ class TcpBtl(Btl):
         with conn.wlock:
             conn.dead = err
             conn.wq.clear()
-            conn.wbuf.clear()
             self._drop_shaped(conn)
         self.log.error("i/o with rank %s failed: %s", conn.peer, err)
         self._unregister(conn)
@@ -1709,7 +1615,6 @@ class TcpBtl(Btl):
             # queued wire copies fold away: every enveloped frame is
             # already retained, replay happens from the window
             conn.wq.clear()
-            conn.wbuf.clear()
             self._drop_shaped(conn)
         self._unregister(conn)  # closes the socket; conn STAYS in conns
         self.log.warning(
@@ -1808,7 +1713,6 @@ class TcpBtl(Btl):
             conn.sock = s
             conn.await_ack = True  # fresh socket, fresh ack word
             conn.rstart = conn.rend = 0
-            conn.rbuf.clear()
             conn.reconnects += 1
         with self._sel_lock:
             try:
@@ -1857,7 +1761,6 @@ class TcpBtl(Btl):
             conn.dead = err
             eof = conn.esc_eof
             conn.wq.clear()
-            conn.wbuf.clear()
             self._drop_shaped(conn)
             conn.retx.clear()
             conn.retx_bytes = 0
@@ -1948,8 +1851,7 @@ class TcpBtl(Btl):
                 oldest = next(iter(conn.retx.values()))[2]
                 if now - oldest <= timeout * (1 + conn.retx_strikes):
                     continue
-                if conn.wbuf or conn.wq or (conn.wqs is not None
-                                            and any(conn.wqs)):
+                if conn.wq or (conn.wqs is not None and any(conn.wqs)):
                     # Local backpressure, not peer silence: the oldest
                     # retained frame may still be queued behind this
                     # conn's own backlog (a bulk storm over small
@@ -2009,10 +1911,6 @@ class TcpBtl(Btl):
         and has done the dead-check."""
         if conn.wqs is None:
             conn.wqs = (deque(), deque(), deque())
-        if conn.wbuf:
-            # legacy residue after a copy_mode flip: ordered first
-            conn.wq.append(bytes(conn.wbuf))
-            conn.wbuf.clear()
         if conn.wq:
             # pre-shaping FIFO residue (mode flip, or frames queued
             # before the peer's QoS ack landed): it must hit the wire
@@ -2239,10 +2137,6 @@ class TcpBtl(Btl):
                 (conn.wqs is not None and any(conn.wqs)):
             # shaped residue after a shape_enable flip: ordered first
             self._fold_shaped_residue(conn)
-        if conn.wbuf:
-            # legacy residue after a copy_mode flip: ordered first
-            conn.wq.appendleft(bytes(conn.wbuf))
-            conn.wbuf.clear()
         wq = conn.wq
         max_vecs = int(_vecs_var._value)
         while wq:
@@ -2328,9 +2222,7 @@ class TcpBtl(Btl):
                     continue
                 if mask & selectors.EVENT_WRITE:
                     with conn.wlock:
-                        if _copy_mode_var._value:
-                            self._flush_legacy(conn)
-                        elif conn.cur is not None or \
+                        if conn.cur is not None or \
                                 (conn.wqs is not None and any(conn.wqs)):
                             # shaped backlog pending (regardless of the
                             # cvar's CURRENT value: a flip mid-backlog
@@ -2464,7 +2356,6 @@ class TcpBtl(Btl):
             # the old socket's partial rx frame is gone with it — the
             # peer's replay covers whatever the tail cut off
             conn.rstart = conn.rend = 0
-            conn.rbuf.clear()
             conn.reconnects += 1
         with self._sel_lock:
             try:
@@ -2475,20 +2366,11 @@ class TcpBtl(Btl):
         return 1
 
     def _drain(self, conn: _Conn) -> int:
-        if _copy_mode_var._value and not conn.rel_rx:
-            # reliability-engaged conns stay on the pooled parser even
-            # under copy_mode: the legacy parser cannot interpret the
-            # per-frame envelope/control flags
-            return self._drain_legacy(conn)
         # pooled receive staging: recv_into this conn's reusable block
         # (one pool hit) instead of a fresh 1 MiB allocation per recv —
         # a 4-byte ack used to cost a megabyte of garbage plus an rbuf
         # concat. Frames are then SLICED out of the block; anything
         # that must outlive it is copied at the pml delivery boundary.
-        if conn.rbuf:
-            # legacy residue after a copy_mode flip: replay it through
-            # the block so frame parsing stays continuous
-            self._adopt_legacy_rbuf(conn)
         buf = conn.rxb
         if buf is None:
             buf = conn.rxb = _rx_pool.acquire()  # owns: rxb
@@ -2509,8 +2391,7 @@ class TcpBtl(Btl):
                 nbuf[:pending] = buf
                 # only a pool-sized block goes back: regrowing an
                 # ALREADY-grown buffer (a second jumbo outgrowing the
-                # first, or legacy-residue adoption that exactly filled
-                # its grown buffer) used to release the private
+                # first) used to release the private
                 # bytearray here, spuriously decrementing the pool's
                 # outstanding count for a block it never handed out
                 if len(buf) == _RX_BLOCK:
@@ -2657,15 +2538,6 @@ class TcpBtl(Btl):
                 conn.rx_frames += 1
                 conn.unacked_n += 1
                 conn.unacked_b += total
-                if _copy_mode_var._value:
-                    # legacy A/B discipline on an enveloped link: the
-                    # legacy parser cannot read envelope flags, so the
-                    # pooled parser reproduces its per-frame parse copy
-                    # here — copy_mode=1 keeps measuring the copying
-                    # baseline on reliable conns too
-                    hdr = bytes(hdr)  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-                    payload = bytes(payload)  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-                    _ctr["copied"] += len(hdr) + len(payload)
                 if word & _ZFLAG:
                     try:
                         payload = zlib.decompress(payload)
@@ -2698,13 +2570,6 @@ class TcpBtl(Btl):
             hdr = mv[start:start + HDR_SIZE]
             payload = mv[start + HDR_SIZE:start + total]
             off = start + total
-            if _copy_mode_var._value:
-                # same legacy A/B parse-copy discipline for the
-                # plain-framed frames a reliable conn carries (the
-                # pre-negotiation tail)
-                hdr = bytes(hdr)  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-                payload = bytes(payload)  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-                _ctr["copied"] += total
             if word & _ZFLAG:
                 # negotiated framing: only a handshake-capable peer ever
                 # sets the flag, so this build always knows how to undo
@@ -2741,110 +2606,6 @@ class TcpBtl(Btl):
                 conn.rxb = None
         else:
             conn.rstart = off
-        return n
-
-    def _adopt_legacy_rbuf(self, conn: _Conn) -> None:
-        """Move legacy rbuf residue (a copy_mode flip mid-stream) into
-        the pooled block, growing it if needed. Runs under the drain's
-        single-drainer exclusivity."""
-        pending = len(conn.rbuf)
-        if conn.rxb is None:
-            conn.rxb = _rx_pool.acquire()  # owns: rxb
-            conn.rstart = conn.rend = 0
-        live = conn.rend - conn.rstart
-        if live + pending > len(conn.rxb):
-            nbuf = bytearray(max(live + pending, 2 * len(conn.rxb)))
-            nbuf[:live] = conn.rxb[conn.rstart:conn.rend]
-            if len(conn.rxb) == _RX_BLOCK:
-                _rx_pool.release(conn.rxb)
-            conn.rxb = nbuf
-            conn.rstart, conn.rend = 0, live
-        elif conn.rend + pending > len(conn.rxb):
-            conn.rxb[:live] = conn.rxb[conn.rstart:conn.rend]
-            conn.rstart, conn.rend = 0, live
-        conn.rxb[conn.rend:conn.rend + pending] = conn.rbuf
-        conn.rend += pending
-        _ctr["copied"] += pending
-        conn.rbuf.clear()
-
-    def _drain_legacy(self, conn: _Conn) -> int:
-        """The pre-vectored read path, verbatim (btl_tcp_copy_mode=1,
-        the bench A/B baseline): a fresh 1 MiB allocation per recv, an
-        rbuf concat, and per-frame header/payload parse copies — all
-        charged to btl_tcp_bytes_copied so the legacy copy tax is
-        measured on the real legacy code."""
-        if conn.rxb is not None and conn.rend > conn.rstart:
-            # vectored residue after a copy_mode flip
-            conn.rbuf += memoryview(conn.rxb)[conn.rstart:conn.rend]  # mpilint: disable=hot-copy — legacy A/B path adopts the pooled residue
-            _ctr["copied"] += conn.rend - conn.rstart
-        if conn.rxb is not None:
-            if len(conn.rxb) == _RX_BLOCK:
-                _rx_pool.discard(conn.rxb)  # mpiracer: disable=cross-thread-race — BufferPool serializes internally (_plock); discard never recycles, so the racing drain keeps sole ownership
-            conn.rxb = None
-            conn.rstart = conn.rend = 0
-        try:
-            data = conn.sock.recv(1 << 20)
-        except socket.error as e:
-            if e.errno in (errno.EAGAIN, errno.EWOULDBLOCK):
-                return 0
-            self._conn_failed(conn, e)
-            return 0
-        if not data:
-            if conn.dead is None:
-                conn.dead = ConnectionResetError("closed by peer")
-            if conn.peer is not None:
-                from ompi_tpu.ft.detector import mark_failed
-
-                if get_var("ft", "enable"):
-                    mark_failed(conn.peer)
-            self._unregister(conn)
-            return 0
-        _ctr["wire"] += len(data)
-        if _forensics._enable_var._value:  # last-rx dump evidence
-            conn.last_rx = time.monotonic()
-        conn.rbuf += data  # mpilint: disable=hot-copy — legacy A/B path reproduces the old rbuf concat on purpose
-        _ctr["copied"] += len(data)
-        n = 0
-        buf = conn.rbuf
-        off = 0
-        if conn.await_ack and len(buf) >= 4:
-            word = _LEN.unpack_from(buf, 0)[0]
-            conn.await_ack = False
-            if word in _ZACK_WORDS:
-                conn.peer_z = bool(word & _ZACK_ACCEPT)
-                conn.peer_q = bool(word & _ZACK_QOS)
-                if word & _ZACK_RELIABLE:
-                    # engaged mid-copy_mode: the NEXT drain dispatches
-                    # to the pooled parser (it alone reads the
-                    # per-frame envelope flags)
-                    conn.rel = conn.rel_rx = True
-                off = 4
-        while len(buf) - off >= 4:
-            word = _LEN.unpack_from(buf, off)[0]
-            total = word & _LEN_MASK
-            if len(buf) - off - 4 < total:
-                break
-            start = off + 4
-            hdr = bytes(buf[start:start + HDR_SIZE])  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-            payload = bytes(buf[start + HDR_SIZE:start + total])  # mpilint: disable=hot-copy — legacy A/B path reproduces the old parse copy on purpose
-            _ctr["copied"] += total
-            off += 4 + total
-            if word & _ZFLAG:
-                try:
-                    payload = zlib.decompress(payload)
-                except zlib.error as e:
-                    self.log.exception("corrupt compressed frame")
-                    self._conn_failed(conn, OSError(
-                        f"corrupt compressed frame from rank "
-                        f"{conn.peer}: {e}"))
-                    return n
-            try:
-                self.deliver(hdr, payload)
-            except Exception:
-                self.log.exception("frame handler failed (frame dropped)")
-            n += 1
-        if off:
-            del buf[:off]
         return n
 
     def _unregister(self, conn: _Conn) -> None:

@@ -20,9 +20,6 @@ buffer or the ring accumulator — via the ``(nbytes, src, dest)`` recv
 form. A staging copy happens only where the data genuinely cannot be
 borrowed (non-contiguous layouts, the bruck rotation, padded ring
 tails) and every such copy is charged to ``coll_round_bytes_copied``.
-The pre-PR-10 staging (fresh recv buffers, recv->out copies, the bruck
-concatenate, the ring segment scratch + gather) is kept VERBATIM behind
-``coll_round_copy_mode=1`` as the measured A/B baseline.
 
 Reduction-bearing schedules (recursive doubling, ring, binomial reduce)
 require a commutative op — the decision layer (coll/tuned.py) routes
@@ -78,11 +75,8 @@ def _unpack_into(data: np.ndarray, buf) -> None:
 def _direct_view(buf) -> Optional[np.ndarray]:
     """Flat uint8 view over the receive buffer so rounds can land
     payloads in their FINAL location (no staging, no final unpack), or
-    None when staging is required: non-contiguous datatype or layout —
-    or the legacy engine, which always stages (that difference is
-    exactly what the copy_mode A/B measures)."""
-    if _sched.copy_mode():
-        return None
+    None when staging is required: non-contiguous datatype or
+    layout."""
     obj, count, dt = parse_buffer(buf)
     if dt.is_contiguous and isinstance(obj, np.ndarray) \
             and obj.flags.c_contiguous and obj.flags.writeable:
@@ -262,8 +256,7 @@ def allreduce_ring(comm, sendbuf, recvbuf, op: _op.Op, nseg: int = 1):
     step — the pool's steady state."""
     n, r = comm.size, comm.rank
     packed, _, dt = _packed(recvbuf if sendbuf is None else sendbuf)
-    legacy = _sched.copy_mode()
-    rdest = None if legacy else _direct_view(recvbuf)
+    rdest = _direct_view(recvbuf)
     if rdest is not None and rdest.nbytes == packed.nbytes \
             and dt.np_dtype is not None:
         # accumulate in the receive buffer itself: seed it with the send
@@ -287,11 +280,11 @@ def allreduce_ring(comm, sendbuf, recvbuf, op: _op.Op, nseg: int = 1):
         a, b = bounds[s], bounds[s + 1]
         ln = b - a
         k = max(1, -(-ln // n))
-        if not legacy and ln == n * k:
+        if ln == n * k:
             segs.append([typed[a:b], k, ln, a, False])  # alias, no copy
         else:
-            # legacy engine verbatim — and the padded-tail fallback: a
-            # non-divisible segment stages into padded scratch, counted
+            # padded-tail fallback: a non-divisible segment stages into
+            # padded scratch, counted
             arr = np.zeros(n * k, dtype=typed.dtype)
             arr[:ln] = typed[a:b]
             _sched.note_copied(ln * typed.itemsize)
@@ -314,7 +307,7 @@ def allreduce_ring(comm, sendbuf, recvbuf, op: _op.Op, nseg: int = 1):
                 sb, rb = (r + 1 - ag) % n, (r - ag) % n
                 kind = "ag"
             sends.append((_bytes(arr[sb * k:(sb + 1) * k]), right))
-            if kind == "ag" and not legacy:
+            if kind == "ag":
                 # the forwarded block IS final data: land it in place
                 recvs.append((k * isz, left,
                               _bytes(arr[rb * k:(rb + 1) * k])))
@@ -330,17 +323,7 @@ def allreduce_ring(comm, sendbuf, recvbuf, op: _op.Op, nseg: int = 1):
                 blk = arr[rb * k:(rb + 1) * k]
                 arr[rb * k:(rb + 1) * k] = _np_reduce_typed(op, blk, got)
                 done_blocks.append(b)  # operand consumed: recycle next yield
-            elif legacy:
-                arr[rb * k:(rb + 1) * k] = b.view(arr.dtype)
-                _sched.note_copied(k * arr.itemsize)
-            # (new engine: ag blocks landed in their final slot already)
-    if legacy:
-        out = np.empty(total, dtype=typed.dtype)
-        for arr, k, ln, off, _staged in segs:
-            out[off:off + ln] = arr[:ln]
-        _sched.note_copied(total * typed.itemsize)
-        _unpack_staging(out, recvbuf)
-        return
+            # (ag blocks landed in their final slot already)
     for arr, k, ln, off, staged in segs:
         if staged:  # padded-tail scratch folds back, counted
             typed[off:off + ln] = arr[:ln]
@@ -362,7 +345,7 @@ def allgather_ring(comm, sendbuf, recvbuf):
     dest = _direct_view(recvbuf)
     out = dest if dest is not None else np.empty(n * nb, dtype=np.uint8)
     out[r * nb:(r + 1) * nb] = block
-    _sched.note_copied(nb)  # own-block placement (both engines)
+    _sched.note_copied(nb)  # own-block placement
     cur = out[r * nb:(r + 1) * nb]
     for d in range(1, n):
         src = (r - d) % n
@@ -385,37 +368,11 @@ def allgather_bruck(comm, sendbuf, recvbuf):
     """Bruck: ceil(log2 n) rounds of doubling block trains
     (coll_base_allgather.c bruck) — latency-optimal for small messages.
     The train lives in ONE flat accumulator: each send is a contiguous
-    view of its head, each recv lands at its tail — the per-round
-    concatenate of the legacy engine is gone; only the final bruck
+    view of its head, each recv lands at its tail; only the final bruck
     rotation copies (counted)."""
     n, r = comm.size, comm.rank
     block, _, _ = _packed(sendbuf)
     nb = block.nbytes
-    if _sched.copy_mode():
-        # legacy engine verbatim: list-of-blocks train, concatenated
-        # into a fresh send buffer every round — the measured baseline
-        acc: List[np.ndarray] = [block]
-        dist = 1
-        while dist < n:
-            cnt = min(dist, n - dist)
-            if cnt > 1:
-                send_data = _bytes(np.concatenate(  # mpilint: disable=hot-copy — legacy copy_mode=1 A/B baseline, counted
-                    [np.frombuffer(b, np.uint8) for b in acc[:cnt]]))
-                _sched.note_copied(send_data.nbytes)
-            else:
-                send_data = _bytes(acc[0])
-            bufs = yield Round(sends=[(send_data, (r - dist) % n)],
-                               recvs=[(cnt * nb, (r + dist) % n)])
-            got = bufs[0]
-            acc.extend(got[i * nb:(i + 1) * nb] for i in range(cnt))
-            dist <<= 1
-        out = np.empty(n * nb, dtype=np.uint8)
-        for i in range(n):
-            src = (r + i) % n
-            out[src * nb:(src + 1) * nb] = acc[i]
-        _sched.note_copied(n * nb)
-        _unpack_staging(out, recvbuf)
-        return
     accbuf = np.empty(n * nb, dtype=np.uint8)
     accbuf[:nb] = block
     _sched.note_copied(nb)

@@ -1,6 +1,6 @@
 """Frozen collective dispatch plans — the verb-layer dispatch-tax killer.
 
-A pre-PR-1 bench record (not measured on the chip) put the per-verb
+An early host-side measurement (not on the chip) put the per-verb
 layer overhead at 20-50us on top of a ~1.8us stub prologue: every
 ``ProcComm._coll`` re-did the slot lookup and re-tested the
 metrics/sanitizer/trace live Vars, and every enabled instrumentation

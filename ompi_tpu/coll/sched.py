@@ -43,9 +43,7 @@ Datapath discipline (the PR 9 btl contract, extended up to this layer):
   not depend on in-flight unordered results; only when the generator
   resumes has every earlier round completed.
 - **measured, not estimated**: ``coll_round_bytes_copied`` /
-  ``bytes_moved`` / ``pool_hits`` / ``windowed`` pvars, with the legacy
-  engine (fresh ``np.empty`` per recv, staged recv->dest copies) kept
-  behind ``coll_round_copy_mode=1`` as the A/B baseline.
+  ``bytes_moved`` / ``pool_hits`` / ``windowed`` pvars.
 """
 
 from __future__ import annotations
@@ -78,13 +76,6 @@ _window_var = register_var(
          "lockstep, the pre-PR-10 barrier-per-round behavior). Only "
          "rounds yielded with ordered=False window; an ordered round "
          "is a full barrier.", level=6)
-_copy_mode_var = register_var(
-    "coll_round", "copy_mode", 0,
-    help="1 = legacy round engine (fresh np.empty per recv, staged "
-         "recv->dest copies, algorithm-side concat/ascontiguousarray "
-         "staging) kept verbatim for the bench A/B — the copies feed "
-         "coll_round_bytes_copied either way, so copies-per-byte-moved "
-         "is measured, not estimated", level=8)
 
 # measured datapath counters (read via the coll_round_* pvars):
 # copied = staging bytes the round engine/algorithms duplicated;
@@ -106,7 +97,7 @@ def _bump(key: str, n: int = 1) -> None:
 
 register_pvar("coll_round", "bytes_copied", lambda: _ctr["copied"],
               help="Staging bytes copied by the collective round engine "
-                   "and its algorithms (legacy A/B baseline included)")
+                   "and its algorithms")
 register_pvar("coll_round", "bytes_moved", lambda: _ctr["moved"],
               help="Payload bytes carried by round sends+recvs — the "
                    "denominator of copies-per-byte-moved")
@@ -131,12 +122,6 @@ def _persist():
 
         _persist_mod = persist
     return _persist_mod
-
-
-def copy_mode() -> bool:
-    """True when the legacy (copying) round engine is armed — the
-    algorithms branch to their verbatim pre-PR-10 staging on it."""
-    return bool(_copy_mode_var._value)
 
 
 # ------------------------------------------------------- stall forensics
@@ -313,14 +298,9 @@ class _RoundState:
 
 def _issue(comm, rnd: Round, tag: int, cid: int, state: _RoundState):
     """Post the round's receives then sends. Returns
-    (requests, recv_bufs, postcopies): ``postcopies`` is the legacy
-    engine's deferred recv->dest staging — (dest, staging, nbytes)
-    triples applied (and counted) after the round completes, exactly
-    where the pre-PR-10 algorithms did ``out[...] = bufs[i]``."""
+    (requests, recv_bufs)."""
     reqs = []
     bufs: List[np.ndarray] = []
-    post: List[tuple] = []
-    legacy = _copy_mode_var._value
     moved = 0
     tr = _trace.enabled()
     if tr:
@@ -333,21 +313,9 @@ def _issue(comm, rnd: Round, tag: int, cid: int, state: _RoundState):
         nbytes, src = rec[0], rec[1]
         dest = rec[2] if len(rec) > 2 else None
         moved += nbytes
-        if legacy:
-            # the legacy engine, verbatim: a fresh allocation per recv,
-            # then a staged copy into the caller's destination
-            buf = np.empty(nbytes, dtype=np.uint8)
-            if dest is not None:
-                post.append((dest, buf, nbytes))
-                bufs.append(dest)
-            else:
-                bufs.append(buf)
-        elif dest is not None:
-            buf = dest  # zero staging: the payload lands in place
-            bufs.append(dest)
-        else:
-            buf = state.alloc(nbytes)
-            bufs.append(buf)
+        # a dest view means zero staging: the payload lands in place
+        buf = dest if dest is not None else state.alloc(nbytes)
+        bufs.append(buf)
         reqs.append(comm.pml.irecv(buf, nbytes, BYTE,
                                    comm.group.world_rank(src), tag, cid))
     for data, dst in rnd.sends:
@@ -370,14 +338,7 @@ def _issue(comm, rnd: Round, tag: int, cid: int, state: _RoundState):
                            cid=cid, tag=tag, round=state.rounds,
                            chunk=rnd.chunk, plane=rnd.plane,
                            sends=len(rnd.sends), recvs=len(rnd.recvs))
-    return reqs, bufs, post
-
-
-def _apply_post(post) -> None:
-    """Legacy staged recv->dest copies, charged to the copy budget."""
-    for dest, staging, nbytes in post:
-        dest[:nbytes] = staging[:nbytes]
-        _bump("copied", nbytes)
+    return reqs, bufs
 
 
 def run_blocking(comm, gen: Schedule, tag: int, cid: int) -> None:
@@ -390,7 +351,7 @@ def run_blocking(comm, gen: Schedule, tag: int, cid: int) -> None:
     first error. Pool blocks recycle only on clean completion;
     any failure path discards them."""
     state = _RoundState()
-    inflight: deque = deque()  # (reqs, postcopies) of unordered rounds
+    inflight: deque = deque()  # request lists of unordered rounds
     first_error: Optional[MPIError] = None
     fx_key = None
     if _forensics._enable_var._value:  # forensics check-in
@@ -400,7 +361,7 @@ def run_blocking(comm, gen: Schedule, tag: int, cid: int) -> None:
                                       "round": 0,
                                       "born": time.monotonic()}
 
-    def retire(reqs, post) -> None:
+    def retire(reqs) -> None:
         nonlocal first_error
         for r in reqs:
             try:
@@ -408,8 +369,6 @@ def run_blocking(comm, gen: Schedule, tag: int, cid: int) -> None:
             except MPIError as e:
                 if first_error is None:
                     first_error = e
-        if first_error is None:
-            _apply_post(post)
 
     bufs: Optional[List[np.ndarray]] = None
     first = True
@@ -427,33 +386,33 @@ def run_blocking(comm, gen: Schedule, tag: int, cid: int) -> None:
                         ent["round"] += 1
             if rnd.free:
                 state.free(rnd.free)
-            reqs, bufs, post = _issue(comm, rnd, tag, cid, state)
+            reqs, bufs = _issue(comm, rnd, tag, cid, state)
             window = _window_var._value
             if rnd.ordered or window <= 1:
                 while inflight:
-                    retire(*inflight.popleft())
-                retire(reqs, post)
+                    retire(inflight.popleft())
+                retire(reqs)
             elif rnd.wait:
                 # self-wait: this round's own results gate the resume,
                 # earlier unordered rounds keep flying (the cross-phase
                 # pipelining contract)
                 if inflight:
                     _bump("windowed")
-                retire(reqs, post)
+                retire(reqs)
             else:
                 _bump("windowed")
-                inflight.append((reqs, post))
+                inflight.append(reqs)
                 while len(inflight) >= max(1, window):
-                    retire(*inflight.popleft())
+                    retire(inflight.popleft())
             if first_error is not None:
                 raise first_error
         while inflight:
-            retire(*inflight.popleft())
+            retire(inflight.popleft())
         if first_error is not None:
             raise first_error
     except BaseException:
         while inflight:
-            retire(*inflight.popleft())
+            retire(inflight.popleft())
         state.discard_all()
         raise
     finally:
@@ -540,8 +499,8 @@ class NbcRequest(Request):
             if rnd.free:
                 with self._lock:
                     self._state.free(rnd.free)
-            reqs, next_bufs, post = _issue(self._comm, rnd, self._tag,
-                                           self._cid, self._state)
+            reqs, next_bufs = _issue(self._comm, rnd, self._tag,
+                                     self._cid, self._state)
             window = max(1, _window_var._value)
             ordered = rnd.ordered or window <= 1
             wait_self = not ordered and rnd.wait
@@ -552,7 +511,7 @@ class NbcRequest(Request):
                     # resume only once every in-flight batch retires
                     with self._lock:
                         if self._inflight > 0:
-                            self._wait_batch = {"n": 0, "post": (),
+                            self._wait_batch = {"n": 0,
                                                 "bufs": next_bufs}
                             self._gen_running = False
                             return
@@ -560,7 +519,7 @@ class NbcRequest(Request):
                 continue
             # Hold one extra token so synchronous completions loop here
             # instead of recursing through the callback.
-            batch = {"n": len(reqs) + 1, "post": post, "bufs": next_bufs}
+            batch = {"n": len(reqs) + 1, "bufs": next_bufs}
             with self._lock:
                 self._inflight += 1
             for r in reqs:
@@ -571,9 +530,6 @@ class NbcRequest(Request):
                 batch["n"] -= 1
                 done_now = batch["n"] == 0
                 if done_now:
-                    if not self._child_error:
-                        _apply_post(batch["post"])
-                    batch["post"] = ()
                     self._inflight -= 1
                     barrier_ok = self._inflight == 0
                 else:
@@ -616,11 +572,6 @@ class NbcRequest(Request):
             batch["n"] -= 1
             if batch["n"] != 0:
                 return
-            # batch retired: apply its legacy staging copies while the
-            # lock orders them before any generator resume
-            if not self._child_error:
-                _apply_post(batch["post"])
-            batch["post"] = ()
             self._inflight -= 1
             if self._gen_running or self._finishing:
                 pass  # the driving thread observes the new state itself

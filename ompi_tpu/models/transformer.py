@@ -36,10 +36,6 @@ class Config:
     d_ff: int = 512
     seq_len: int = 128
     lr: float = 1e-2
-    # rematerialize each block's activations in backward (jax.checkpoint):
-    # trades ~30% more FLOPs for O(layers) less HBM — the standard TPU
-    # memory/compute exchange, letting batch sizes that keep the MXU busy
-    remat: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -133,7 +129,7 @@ def _mm(a, w):
 
 
 def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
-                   in_mesh: bool = False, causal_ring: bool = True):
+                   in_mesh: bool = False):
     """Forward on local shards up to the final layernorm (pre-logits
     features [B, T, D]). Inside shard_map (``in_mesh=True``): tokens
     [B/dp, S/sp]; tp-sharded weights arrive as local slices; activations
@@ -183,7 +179,7 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
                 # full-tile chunk: the flash/recompute backward keeps the
                 # dense tile memory-safe; long-seq configs shrink the tile
                 # via the chunk arg (lax fallback only)
-                att = ring_attention(q, k, v, "sp", sp, causal=causal_ring,
+                att = ring_attention(q, k, v, "sp", sp, causal=True,
                                      mxu_dtype=jnp.bfloat16, chunk=T,
                                      layout="bhtd")
             else:
@@ -215,8 +211,6 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
                 ff = axes.allreduce(ff, "tp")
             return x + ff
 
-    if cfg.remat:
-        block = jax.checkpoint(block)
     for blk in params["blocks"]:
         x = block(x, blk)
 
@@ -224,14 +218,13 @@ def features_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
 
 
 def forward_local(params, tokens, cfg: Config, tp: int = 1, sp: int = 1,
-                  in_mesh: bool = False, causal_ring: bool = True):
+                  in_mesh: bool = False):
     """Forward to logits [B, T, vocab] (dense — for inference/tests; the
     training loss streams the vocab projection in chunks instead and
     forms its gradient in the same pass, see ops/softmax_xent.py)."""
     from ompi_tpu.ops.softmax_xent import logits_matmul
 
-    x = features_local(params, tokens, cfg, tp=tp, sp=sp, in_mesh=in_mesh,
-                       causal_ring=causal_ring)
+    x = features_local(params, tokens, cfg, tp=tp, sp=sp, in_mesh=in_mesh)
     return logits_matmul(x, params["embed"])
 
 

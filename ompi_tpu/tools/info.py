@@ -47,10 +47,10 @@ def _load_everything() -> None:
     import ompi_tpu.coll.quant  # quantized-collectives component
     import ompi_tpu.coll.hier.compose  # hier composer + coll_hier cvars
     import ompi_tpu.coll.hier  # hier_plan_hits/misses/retunes pvars
-    import ompi_tpu.btl.tcp  # btl_tcp compress/writev/copy_mode + reliable/retx_*/link_* cvars, datapath + link pvars
+    import ompi_tpu.btl.tcp  # btl_tcp compress/writev + reliable/retx_*/link_* cvars, datapath + link pvars
     import ompi_tpu.runtime.progress  # idle-block cvar + progress_idle_blocks pvar
     import ompi_tpu.runtime.mpool  # BufferPool mpool_pool_* pvars
-    import ompi_tpu.coll.sched  # coll_round_* window/copy_mode cvars + datapath pvars
+    import ompi_tpu.coll.sched  # coll_round_window cvar + datapath pvars
     import ompi_tpu.coll.persist  # coll_persist_* cvars + persist_* replay pvars
     import ompi_tpu.qos  # QoS classes: btl_tcp_shape_enable/segment + qos_* cvars/pvars
     import ompi_tpu.runtime.forensics  # stall-forensics cvars + forensics_* pvars

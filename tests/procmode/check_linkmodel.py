@@ -25,10 +25,6 @@ injection, selected by argv[1]. All modes run with
     delivered payload; the wrapper runs it with linkmodel (and the
     active probe) on and off and asserts identical digests.
 
-``stats`` — 2 ranks, healthy link: pumps bulk traffic with folds in
-    between and prints the edge row (``LINKBENCH ...``) for bench.py's
-    gauge mirror.
-
 Reference analogs: check_link.py (reliability scenarios) — this file
 is its telemetry sibling.
 """
@@ -151,32 +147,6 @@ def equal_mode() -> int:
     return 0
 
 
-def stats_mode() -> int:
-    import ompi_tpu
-    from ompi_tpu import COMM_WORLD
-    from ompi_tpu.runtime import linkmodel
-
-    r = COMM_WORLD.Get_rank()
-    # bulk rounds with folds in between: the goodput EWMA needs >= 2
-    # spaced folds to read a rate
-    for round_ in range(4):
-        _pump(COMM_WORLD, r, peers_of_zero=(1,), iters=8, words=8192)
-        linkmodel._fold(force=True)
-        time.sleep(0.06)  # > _FOLD_MIN_S so the next fold rates a dt
-    COMM_WORLD.Barrier()
-    if r == 0:
-        by_dst = _edges_by_dst()
-        e = by_dst[1]
-        goodput = sum(e["goodput_bps"].values())
-        assert e["rtt_samples"] > 0 and goodput > 0.0, e
-        print(f"LINKBENCH rank 0 srtt_us={e['srtt_us']} "
-              f"goodput_bps={goodput:.1f} loss_ppm={e['loss_ppm']}",
-              flush=True)
-    print(f"rank {r}: LINKSTATS-OK", flush=True)
-    ompi_tpu.Finalize()
-    return 0
-
-
 def main() -> int:
     faulthandler.register(_signal.SIGUSR1)  # hang diagnosis: kill -USR1
     mode = sys.argv[1]
@@ -186,8 +156,6 @@ def main() -> int:
         return corrupt_mode()
     if mode == "equal":
         return equal_mode()
-    if mode == "stats":
-        return stats_mode()
     print(f"unknown mode {mode}", flush=True)
     return 2
 

@@ -1,19 +1,13 @@
-"""Zero-copy vectored tcp datapath A/B + idle-blocking proof.
+"""Zero-copy vectored tcp datapath + idle-blocking proof.
 
-Run with 2 ranks over tcp only (``--mca btl_btl ^sm``). Interleaved
-min-of-rounds (the repo's noise discipline, PR 8 plan-cache
-methodology): each round measures the zero-copy vectored path and the
-legacy copying path (``btl_tcp_copy_mode=1``) back to back, so host
-drift cancels.
-
-Three claims, two of them count-based (deterministic):
+Run with 2 ranks over tcp only (``--mca btl_btl ^sm``). Two claims,
+both count-based (deterministic):
 
 - copies-per-wire-byte at a 32 MB rendezvous, measured from the
-  btl_tcp_bytes_copied / btl_tcp_wire_bytes pvars — not estimated;
+  btl_tcp_bytes_copied / btl_tcp_wire_bytes pvars — not estimated —
+  under each rank's bound;
 - a quiet rank's progress loop parks in select
-  (progress_idle_blocks > 0);
-- small-message rate and rendezvous bandwidth ratios (timing — printed
-  for bench.py, asserted only loosely here).
+  (progress_idle_blocks > 0).
 """
 
 import time
@@ -21,7 +15,7 @@ import time
 import numpy as np
 
 from ompi_tpu import COMM_WORLD
-from ompi_tpu.mca.var import all_pvars, set_var
+from ompi_tpu.mca.var import all_pvars
 
 comm = COMM_WORLD
 r = comm.Get_rank()
@@ -41,29 +35,32 @@ assert type(comm.pml.endpoints[comm._world_rank(peer)]).__name__ \
     == "TcpBtl", "run with --mca btl_btl ^sm"
 
 SMALL = 4096
-K = 64        # outstanding small messages per direction per batch
-N_BATCH = 6
+K = 64        # outstanding small messages per direction
+# Per rank, half the lowest copies per wire byte the copying datapath
+# this one replaced ever measured on the 32 MB rendezvous (sender
+# 2.999929909255432, receiver 1.000003367448467, over 12 runs): the old
+# gate asked that path for at least twice this path's copies, so half
+# of it is the most it allowed. The sender's ~1.0 is the one owned copy
+# of what the kernel declines under backpressure.
+COPIES_BOUND = {0: 2.999929 / 2, 1: 1.000003 / 2}
 big = np.arange((32 << 20) // 8, dtype=np.float64)
 dst_big = np.zeros_like(big)
 small = np.zeros(SMALL, np.uint8)
 dst_small = [np.zeros(SMALL, np.uint8) for _ in range(K)]
 
 
-def small_rate(n):
-    """Batched small-message stream: K outstanding eager sends per
-    direction — message RATE (per-message CPU tax), not pingpong
-    latency, which is wait-loop-bound and hides the copy cost."""
-    for _ in range(n):
-        if r == 0:
-            sr = [comm.Isend(small, dest=1, tag=30 + i) for i in range(K)]
-            rr = [comm.Irecv(dst_small[i], source=1, tag=130 + i)
-                  for i in range(K)]
-        else:
-            rr = [comm.Irecv(dst_small[i], source=0, tag=30 + i)
-                  for i in range(K)]
-            sr = [comm.Isend(small, dest=0, tag=130 + i) for i in range(K)]
-        for q in sr + rr:
-            q.Wait()
+def small_batch():
+    """K outstanding eager sends per direction."""
+    if r == 0:
+        sr = [comm.Isend(small, dest=1, tag=30 + i) for i in range(K)]
+        rr = [comm.Irecv(dst_small[i], source=1, tag=130 + i)
+              for i in range(K)]
+    else:
+        rr = [comm.Irecv(dst_small[i], source=0, tag=30 + i)
+              for i in range(K)]
+        sr = [comm.Isend(small, dest=0, tag=130 + i) for i in range(K)]
+    for q in sr + rr:
+        q.Wait()
 
 
 def rendezvous():
@@ -73,68 +70,30 @@ def rendezvous():
         comm.Recv(dst_big, source=0, tag=20)
 
 
-def timed(fn, *a):
-    comm.Barrier()
-    t0 = time.perf_counter()
-    fn(*a)
-    comm.Barrier()
-    return time.perf_counter() - t0
-
-
-# correctness first, both modes — these must NEVER flake
-for mode in (0, 1):
-    set_var("btl_tcp", "copy_mode", mode)
-    rendezvous()
-    if r == 1:
-        np.testing.assert_array_equal(dst_big, big)
-        dst_big[:] = 0
-    small_rate(1)
-    for d in dst_small:
-        np.testing.assert_array_equal(d, small)
-set_var("btl_tcp", "copy_mode", 0)
+# correctness first — this must NEVER flake
+rendezvous()
+if r == 1:
+    np.testing.assert_array_equal(dst_big, big)
+    dst_big[:] = 0
+small_batch()
+for d in dst_small:
+    np.testing.assert_array_equal(d, small)
 print(f"P2P-CORRECT rank {r}", flush=True)
 
-# copies-per-wire-byte, from pvars: one 32 MB rendezvous per mode.
-# Count-based — deterministic enough to gate on (the zero-copy path's
-# only copies are backpressure-dependent, so the RATIO vs legacy is
-# asserted, with legacy's floor pinned by construction).
-ratios = {}
-for mode, name in ((0, "zero"), (1, "legacy")):
-    set_var("btl_tcp", "copy_mode", mode)
-    comm.Barrier()
-    c0, w0, _ = _ctr()
-    rendezvous()
-    comm.Barrier()
-    c1, w1, _ = _ctr()
-    ratios[name] = (c1 - c0) / max(w1 - w0, 1)
-    if r == 1:
-        np.testing.assert_array_equal(dst_big, big)
-        dst_big[:] = 0
-set_var("btl_tcp", "copy_mode", 0)
-drop = ratios["legacy"] / max(ratios["zero"], 1e-9)
-print(f"P2P-COPIES rank {r} zero={ratios['zero']:.3f} "
-      f"legacy={ratios['legacy']:.3f} drop={drop:.1f}x", flush=True)
-assert ratios["legacy"] >= 2.0 * ratios["zero"], ratios
-assert ratios["legacy"] > 0.9, ratios  # legacy really copies
-
-# timing legs: interleaved min-of-rounds
-t_small = {0: float("inf"), 1: float("inf")}
-t_big = {0: float("inf"), 1: float("inf")}
-for _ in range(5):
-    for mode in (0, 1):
-        set_var("btl_tcp", "copy_mode", mode)
-        t_small[mode] = min(t_small[mode], timed(small_rate, N_BATCH))
-        t_big[mode] = min(t_big[mode], timed(rendezvous))
-set_var("btl_tcp", "copy_mode", 0)
-if r == 0:
-    rate0 = 2 * K * N_BATCH / t_small[0]
-    rate1 = 2 * K * N_BATCH / t_small[1]
-    bw0 = (32 << 20) / t_big[0] / 1e9
-    bw1 = (32 << 20) / t_big[1] / 1e9
-    print(f"P2P-RATE small_zero={rate0:.0f}/s small_legacy={rate1:.0f}/s "
-          f"ratio={rate0 / rate1:.2f}", flush=True)
-    print(f"P2P-BW rv32_zero={bw0:.2f}GB/s rv32_legacy={bw1:.2f}GB/s "
-          f"ratio={bw0 / bw1:.2f}", flush=True)
+# copies-per-wire-byte, from pvars, over one 32 MB rendezvous
+comm.Barrier()
+c0, w0, _ = _ctr()
+rendezvous()
+comm.Barrier()
+c1, w1, _ = _ctr()
+ratio = (c1 - c0) / max(w1 - w0, 1)
+if r == 1:
+    np.testing.assert_array_equal(dst_big, big)
+    dst_big[:] = 0
+print(f"P2P-COPIES rank {r} copies_per_wire_byte={ratio!r} "
+      f"bound={COPIES_BOUND[r]!r}", flush=True)
+assert w1 > w0, "the rendezvous moved no wire bytes"
+assert ratio <= COPIES_BOUND[r], (ratio, COPIES_BOUND[r])
 
 # idle-blocking proof: go quiet and let the ProgressThread's backoff
 # run cold — with tcp+self only (no poll-only transport) it must PARK

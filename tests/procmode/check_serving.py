@@ -38,8 +38,7 @@ corrected) must improve >= 2x with classification on — verdict
 MIN-allreduced, stripe-style <= 3 attempts, correctness asserted on
 every iteration of every attempt.
 
-``steady`` (3 ranks) — no churn: N steps, SLO surface printed (the
-bench_serving baseline leg).
+``steady`` (3 ranks) — no churn: N steps, SLO surface printed.
 """
 
 import faulthandler
@@ -161,9 +160,7 @@ def steady_mode() -> int:
     assert pv["forensics_stall_trips"].value == 0
     if get_var("metrics", "enable"):
         # per-step critical-path breakdown (mean us per category from
-        # the critpath histograms the harness fed) — bench_serving
-        # parses this into the metrics registry so BENCH json ==
-        # Prometheus export (the established mirroring discipline)
+        # the critpath histograms the harness fed)
         snap = metrics.snapshot()
         means = {}
         for cat in ("compute", "wire", "wait", "defer"):

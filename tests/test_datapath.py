@@ -1,11 +1,11 @@
 """Zero-copy vectored tcp datapath + idle-blocking progress.
 
 Covers the write-queue/sendmsg path (ownership, integrity under
-backlog, jumbo-frame rx growth), the measured copy counters and the
-legacy A/B mode, the idle-block select park (fd wake, poke wake,
-timeout, poll-only cap, lost-wakeup recheck), the thread-safe progress
-cadence, and the hot-copy lint rule. The end-to-end numbers live in
-tests/procmode/check_p2p.py and bench.py's p2p section.
+backlog, jumbo-frame rx growth), the measured copy counters and their
+bound, the idle-block select park (fd wake, poke wake, timeout,
+poll-only cap, lost-wakeup recheck), the thread-safe progress cadence,
+and the hot-copy lint rule. The end-to-end numbers live in
+tests/procmode/check_p2p.py.
 """
 
 import threading
@@ -31,7 +31,6 @@ def tcp_pair():
     a.set_peers({1: f"127.0.0.1:{b.port}"})
     b.set_peers({0: f"127.0.0.1:{a.port}"})
     yield a, b, got
-    set_var("btl_tcp", "copy_mode", 0)
     a.finalize()
     b.finalize()
 
@@ -98,41 +97,28 @@ def test_noncontiguous_payload_falls_back_to_copy(tcp_pair):
     assert _ctr["copied"] == c0 + arr.nbytes
 
 
+# The lowest copies per wire byte the copying datapath this one
+# replaced ever measured on this workload (1.999565475918218, the same
+# in 12 of 12 runs): the old gate asked that path for at least twice
+# the vectored path's copies, so half of it is the most it allowed.
+LEGACY_COPIES_PER_WIRE_BYTE = 1.999565
+
+
 def test_copy_mode_ab_is_measured_and_worse(tcp_pair):
-    """btl_tcp_copy_mode=1 runs the real legacy datapath: its measured
-    copies-per-wire-byte must be >= 2x the vectored path's (the
-    count-based acceptance gate, deterministic by construction)."""
+    """The vectored path's copies per wire byte, measured from the
+    counters on eight 64 KiB sends, stay under half the lowest the
+    copying datapath it replaced measured (count-based, deterministic
+    by construction)."""
     a, b, got = tcp_pair
     payload = np.zeros(1 << 16, np.uint8)
-
-    def leg():
-        base = len(got)
-        c0, w0 = _ctr["copied"], _ctr["wire"]
-        for _ in range(8):
-            a.send(1, HDR, payload)
-        _pump([a, b], lambda: len(got) >= base + 8, t=30)
-        return (_ctr["copied"] - c0) / max(_ctr["wire"] - w0, 1)
-
-    set_var("btl_tcp", "copy_mode", 0)
-    zero = leg()
-    set_var("btl_tcp", "copy_mode", 1)
-    legacy = leg()
-    assert legacy >= 2.0 * max(zero, 1e-9), (zero, legacy)
-    assert legacy > 0.9  # send copies alone give ~1.5/byte
-
-
-def test_copy_mode_flip_mid_stream_bridges_residue(tcp_pair):
-    """Flipping copy_mode between frames must not tear the stream:
-    queued/parked residue is folded across the mode boundary."""
-    a, b, got = tcp_pair
-    payload = np.arange(1 << 18, dtype=np.uint8) % 97
-    expect = payload.tobytes()
-    for i in range(12):
-        set_var("btl_tcp", "copy_mode", i % 2)
+    c0, w0 = _ctr["copied"], _ctr["wire"]
+    for _ in range(8):
         a.send(1, HDR, payload)
-    set_var("btl_tcp", "copy_mode", 0)
-    _pump([a, b], lambda: len(got) >= 12, t=30)
-    assert len(got) == 12 and all(g[1] == expect for g in got)
+    _pump([a, b], lambda: len(got) >= 8, t=30)
+    assert len(got) == 8
+    ratio = (_ctr["copied"] - c0) / max(_ctr["wire"] - w0, 1)
+    assert _ctr["wire"] > w0
+    assert ratio <= LEGACY_COPIES_PER_WIRE_BYTE / 2, ratio
 
 
 # -------------------------------------------------------------- idle block
@@ -272,10 +258,8 @@ def test_progress_cadence_is_exact_under_threads():
 # ------------------------------------------------------------ registered
 def test_datapath_cvars_and_pvars_registered():
     vars_ = all_vars()
-    for name in ("btl_tcp_writev_max_vecs", "btl_tcp_copy_mode",
-                 "runtime_idle_block_us"):
+    for name in ("btl_tcp_writev_max_vecs", "runtime_idle_block_us"):
         assert name in vars_, name
-    assert vars_["btl_tcp_copy_mode"].default == 0
     assert vars_["runtime_idle_block_us"].default == 50000
     pvars = all_pvars()
     for name in ("btl_tcp_bytes_copied", "btl_tcp_writev_calls",
@@ -368,7 +352,6 @@ def test_info_cli_lists_datapath_surface(capsys):
     info_main(["--level", "9", "--param", "btl_tcp"])
     out = capsys.readouterr().out
     assert "btl_tcp_writev_max_vecs" in out
-    assert "btl_tcp_copy_mode" in out
     info_main(["--level", "9", "--param", "runtime"])
     out = capsys.readouterr().out
     assert "runtime_idle_block_us" in out
@@ -404,11 +387,10 @@ def test_owned_boundary_copy():
 
 # ---------------------------------------------------------- procmode proof
 def test_p2p_procmode_zero_copy_and_idle_block():
-    """End to end over real sockets: correctness in both copy modes,
-    copies-per-wire-byte measured from the pvars dropping >= 2x vs the
-    legacy datapath, and a quiet rank's progress loop provably parked
-    in select. Count-based gates only — the timing ratios are printed
-    for bench.py (noise discipline: the stripe-test lesson)."""
+    """End to end over real sockets: correctness, copies-per-wire-byte
+    measured from the pvars under each rank's bound, and a quiet rank's
+    progress loop provably parked in select. Count-based gates only
+    (noise discipline: the stripe-test lesson)."""
     from tests.test_process_mode import run_mpi
 
     r = run_mpi(2, "tests/procmode/check_p2p.py", timeout=150,

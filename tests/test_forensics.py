@@ -417,17 +417,14 @@ def test_runtime_disable_clears_the_latch(restore_vars, tmp_path):
 
 
 def test_legacy_wire_paths_stamp_rx_tx_evidence(restore_vars):
-    """btl_tcp_copy_mode=1 (the kept A/B baseline) must stamp
-    last_rx/last_tx like the vectored paths do — a dump on a moving
-    legacy link otherwise shows null wire-liveness, indistinguishable
-    from a silent one (5th review pass)."""
+    """The wire paths stamp last_rx/last_tx while the forensics plane is
+    armed — a dump on a moving link otherwise shows null wire-liveness,
+    indistinguishable from a silent one (5th review pass)."""
     from ompi_tpu.btl.tcp import TcpBtl
     from ompi_tpu.pml.base import pack_header
 
     restore_vars("forensics", "enable")
-    restore_vars("btl_tcp", "copy_mode")
     set_var("forensics", "enable", True)
-    set_var("btl_tcp", "copy_mode", 1)
     got = []
     a = TcpBtl(lambda h, p: got.append(bytes(p)), my_rank=0)
     b = TcpBtl(lambda h, p: got.append(bytes(p)), my_rank=1)

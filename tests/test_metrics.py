@@ -432,9 +432,9 @@ def test_http_endpoint_serves_metrics_and_json(clean_metrics):
 
 
 def test_bench_numbers_flow_into_the_export(clean_metrics):
-    """Satellite contract: bench.py feeds prologue_us / dispatch-tax
-    into the registry, so BENCH json and the Prometheus export report
-    the same numbers."""
+    """Gauges a measuring tool sets (tools/reshardplan.py --bench does)
+    reach the Prometheus export, labels and all, in valid text
+    format."""
     metrics.gauge_set("bench_prologue_us", 1.94)
     metrics.gauge_set("bench_layer_overhead_us", 2.5, verb="allreduce")
     text = metrics.render_prometheus()

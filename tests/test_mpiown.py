@@ -383,7 +383,7 @@ def test_rx_regrow_does_not_release_unpooled_buffer():
 
         grown = bytearray(2 * btl_tcp._RX_BLOCK)  # private, NOT pooled
         conn = types.SimpleNamespace(
-            sock=EagainSock(), rbuf=b"", rxb=grown,
+            sock=EagainSock(), rxb=grown,
             rstart=0, rend=len(grown))
         n = btl_tcp.TcpBtl._drain(object.__new__(btl_tcp.TcpBtl), conn)
         assert n == 0
@@ -409,7 +409,7 @@ def test_rx_regrow_still_releases_the_pooled_block():
             raise socket.error(errno.EAGAIN, "try again")
 
     conn = types.SimpleNamespace(
-        sock=EagainSock(), rbuf=b"", rxb=block,
+        sock=EagainSock(), rxb=block,
         rstart=0, rend=len(block))
     btl_tcp.TcpBtl._drain(object.__new__(btl_tcp.TcpBtl), conn)
     assert len(conn.rxb) == 2 * btl_tcp._RX_BLOCK  # grew past the pool

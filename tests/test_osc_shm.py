@@ -14,8 +14,7 @@ def test_osc_shm_procmode_4ranks():
     assert m, r.stdout
     # performance-ratio floor only under the soak/bench gate: on the
     # loaded shared CI host scheduler noise can flake it (ADVICE r4);
-    # the correctness assertions above are unconditional, and bench.py
-    # records the ratio every round
+    # the correctness assertions above are unconditional
     if os.environ.get("OMPI_TPU_TEST_SOAK"):
         # one mapped memcpy vs frame copy + round trip: decisive even
         # on a loaded single-core host (measured ~69x)

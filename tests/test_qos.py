@@ -6,7 +6,7 @@ send path of btl/tcp.py).
 Unit level: fake sockets and a fake loopback btl make the scheduler and
 the pml reassembly provable without subprocesses. The end-to-end p99
 A/B under a real replication storm lives in
-tests/procmode/check_qos.py and bench.py's qos section.
+tests/procmode/check_qos.py.
 """
 
 import errno

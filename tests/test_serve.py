@@ -565,8 +565,7 @@ def test_serving_churn_procmode(tmp_path):
 def test_serving_steady_procmode():
     """No churn: the SLO surface plus the per-step critical-path
     breakdown (metrics on: every applied step feeds the critpath
-    histograms, and the SERVING-CRIT line bench_serving mirrors into
-    gauges must parse)."""
+    histograms, and the SERVING-CRIT line must parse)."""
     r = run_mpi(3, "tests/procmode/check_serving.py", "steady",
                 timeout=120, mca=(("coll_sm_enable", "0"),
                                   ("metrics_enable", "1")))
@@ -586,8 +585,8 @@ def test_serving_recovery_isolation_ab(tmp_path):
     """Recovery-traffic isolation A/B (acceptance: >= 2x, MIN-
     allreduced, <= 3 stripe-style attempts inside the check). Slow-
     marked: two storm phases x up to 3 attempts is a multi-minute
-    wire-saturating run; bench_serving and the PR record carry the
-    measured numbers (3/3 standalone >= 2x)."""
+    wire-saturating run; the PR record carries the measured numbers
+    (3/3 standalone >= 2x)."""
     r = run_mpi(3, "tests/procmode/check_serving.py", "iso",
                 timeout=420,
                 mca=(("btl_btl", "^sm"),

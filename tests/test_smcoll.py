@@ -15,7 +15,7 @@ def test_smcoll_procmode_4ranks():
     assert m, r.stdout
     # performance-ratio floors only under the soak/bench gate: on the
     # loaded shared CI host scheduler noise can flake them (ADVICE r4);
-    # correctness above is unconditional and bench.py records the ratio
+    # correctness above is unconditional
     if os.environ.get("OMPI_TPU_TEST_SOAK"):
         # the segment path must beat the pml path decisively (VERDICT
         # asks >=2x at 1-16MB). On a single-core host both paths
