@@ -213,7 +213,7 @@ def _steps(mesh, cfg, params, toks, tgts, n_steps: int, kernels: int):
 
 def phase_training(devs, cfg=None, batch=None, n_steps: int = 4) -> None:
     """The flagship step on one device: finite, falling loss, the flash
-    kernel in (3 per layer: forward, dq, dk/dv), smoke timings."""
+    kernel in (2 per layer: forward, backward), smoke timings."""
     import jax
 
     from ompi_tpu.models import transformer as tfm
@@ -226,7 +226,7 @@ def phase_training(devs, cfg=None, batch=None, n_steps: int = 4) -> None:
     say(f"training: {n_params / 1e6:.1f}M params, batch {batch} x "
         f"{cfg.seq_len}")
     losses, times = _steps(_mesh(devs[:1], (1, 1, 1)), cfg, params, toks,
-                           tgts, n_steps, 3 * cfg.n_layers)
+                           tgts, n_steps, 2 * cfg.n_layers)
     say("training: losses " + ", ".join(repr(v) for v in losses))
     check(all(np.isfinite(losses)), "non-finite loss")
     check(losses[-1] < losses[0], "loss did not fall")
@@ -254,10 +254,10 @@ def phase_train_4(devs, cfg=None, batch=None, n_steps: int = 3) -> None:
     params = tfm.init_params(jax.random.PRNGKey(SEED), cfg)
     toks, tgts = _batch(cfg, batch)
     one, _ = _steps(_mesh(devs[:1], (1, 1, 1)), cfg, params, toks, tgts,
-                    n_steps, 3 * cfg.n_layers)
+                    n_steps, 2 * cfg.n_layers)
     # sp=2: each layer runs both ring steps through the kernel
     four, _ = _steps(_mesh(devs, (1, 2, 2)), cfg, params, toks, tgts,
-                     n_steps, 2 * 3 * cfg.n_layers)
+                     n_steps, 2 * 2 * cfg.n_layers)
     say("train 1x2x2: losses " + ", ".join(repr(v) for v in four))
     say("train 1x1x1: losses " + ", ".join(repr(v) for v in one))
     drop_one = [a - b for a, b in zip(one, one[1:])]

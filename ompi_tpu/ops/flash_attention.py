@@ -19,9 +19,10 @@ Contract (shared with the lax fallback in ring_attention.py):
 - ``lse`` uses -1e30 (not -inf) as the empty-row sentinel: every exp/sub
   stays finite, so the ring's (out, lse) merge is AD-safe with no
   where-grad NaN traps.
-- backward = custom_vjp with two Pallas kernels (dq; dk/dv) that RE-SCORE
-  their tiles from the saved (q, k, v, lse) — the flash recompute trade:
-  O(T) residuals instead of O(T^2).
+- backward = custom_vjp with one Pallas kernel that RE-SCORES each
+  (key tile, query tile) pair once from the saved (q, k, v, lse) and
+  forms dq, dk and dv from it — the flash recompute trade: O(T)
+  residuals instead of O(T^2).
 - the lse cotangent is honored (it folds into the delta rows): the ring
   merge differentiates through exp(lse - lse_new), so g_lse != 0 mid-ring.
 """
@@ -71,14 +72,19 @@ def _pick_blocks(tq: int, tk: int, d: int) -> Tuple[int, int]:
     The causal walk over these tiles is ``causal_walk``'s: tiles wholly
     below the diagonal unmasked, and a square diagonal tile in
     ``_DIAG_SUB``-wide sub-blocks that stop at the diagonal, each
-    masking only its own square. Swept on v5e, one layer's fwd + dq +
-    dk/dv at [36, 8, 1024, 128] bf16: whole masked diagonal tiles 4.780
-    ms; off-diagonal tiles unmasked 4.436; diagonal sub-blocks of 256
-    4.382, of 128 4.761 (128-row matmuls cost more than the elements
-    they save, and dk/dv lost at both); with dk/dv scoring keys
-    by queries, so that no matmul takes a transposed operand, 256 gives
-    4.269 and 128 4.252. 64 does not compile: lse rows are lane slices
-    that start at multiples of 128."""
+    masking only its own square. Swept on v5e, one layer's fwd and the
+    backward's then two kernels (dq; dk/dv) at [36, 8, 1024, 128] bf16:
+    whole masked diagonal tiles 4.780 ms; off-diagonal tiles unmasked
+    4.436; diagonal sub-blocks of 256 4.382, of 128 4.761 (128-row
+    matmuls cost more than the elements they save, and dk/dv lost at
+    both); with dk/dv scoring keys by queries, so that no matmul takes
+    a transposed operand, 256 gives 4.269 and 128 4.252. 64 does not
+    compile: lse rows are lane slices that start at multiples of 128.
+    The one backward kernel that replaced the two keeps that
+    orientation, so only its dq matmul takes a transposed operand: fwd
+    1.119 + backward 1.922 ms, where the two kernels took 1.367 + 1.778
+    (scoring queries by keys instead, so that dk and dv take the
+    transposes: 1.968)."""
     cap = 1024 if d < 128 else 512
     bq = cap
     while bq > 1 and tq % bq:
@@ -100,7 +106,7 @@ class Walk(NamedTuple):
     block_q: int
     block_k: int
     sub: int      # diagonal sub-block width; 0 where the split is off
-    visited: int  # score elements each of the three kernels computes
+    visited: int  # score elements each of the two kernels computes
     masked: int   # of those, the elements that pass through the mask
 
 
@@ -117,7 +123,7 @@ def causal_walk(tq: int, tk: int, d: int) -> Walk:
     KV shard of ``tk`` with head dim ``d``: the blocks ``_pick_blocks``
     chooses, the diagonal sub-block (0 where the diagonal tile is not a
     square of whole 128-lane tiles), and the elements a causal call
-    scores and masks per (b, h), the same in all three kernels. A full
+    scores and masks per (b, h), the same in both kernels. A full
     (keep_full) call scores tq*tk elements and masks none."""
     bq, bk = _pick_blocks(tq, tk, d)
     # lse rows are sliced on lanes: a sub-block spans whole 128-lane tiles
@@ -136,6 +142,43 @@ def causal_walk(tq: int, tk: int, d: int) -> Walk:
             visited += (touch - full) * bq * bk
             masked += (touch - full) * bq * bk
     return Walk(bq, bk, sub, visited, masked)
+
+
+# a kernel asks for VMEM beyond the compiler's default scoped limit
+# only as far as it needs, and never for more than _VMEM_MAX of the
+# 128 MiB of a v5e core
+_VMEM_DEFAULT = 16 << 20
+_VMEM_MAX = 100 << 20
+
+
+def _block_bytes(rows: int, d: int, nbytes: int) -> int:
+    """VMEM bytes of a [rows, d] block: lanes are padded to 128."""
+    return rows * -(-d // 128) * 128 * nbytes
+
+
+def vmem_bytes(tq: int, tk: int, d: int, in_bytes: int) -> Tuple[int, int]:
+    """(forward, backward) VMEM bytes of one (b, h) program: each block
+    twice, for the pipeline's two buffers, and eight f32 score tiles of
+    one pair. The forward holds a q tile, the whole k and v, an o tile
+    and its lse rows; the backward the whole q, dO (f32), lse, delta and
+    dq (f32), and a k, v, dk and dv tile."""
+    bq, bk = _pick_blocks(tq, tk, d)
+    scores = 8 * bq * bk * 4
+    fwd = 2 * (_block_bytes(bq, d, in_bytes) + _block_bytes(bq, d, 4)
+               + 2 * _block_bytes(tk, d, in_bytes) + 8 * bq * 4)
+    bwd = 2 * (_block_bytes(tq, d, in_bytes) + 2 * _block_bytes(tq, d, 4)
+               + 2 * 8 * tq * 4 + 2 * _block_bytes(bk, d, in_bytes)
+               + 2 * _block_bytes(bk, d, 4))
+    return fwd + scores, bwd + scores
+
+
+def _vmem_params(nbytes: int):
+    """A kernel's compiler params: the default where ``nbytes`` fits the
+    default scoped limit (a limit set on a kernel is written into the
+    ops around it too), else a limit of ``nbytes``."""
+    if nbytes <= _VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=nbytes)
 
 
 def _causal(s, off, key_axis: int = 1):
@@ -252,6 +295,7 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
     grid = (BH, Tq // block_q)
     kern = functools.partial(_fwd_kernel, walk=walk, n_kv=Tk // block_k,
                              sm_scale=sm_scale)
+    in_bytes = max(x.dtype.itemsize for x in (q3, k3, v3))
     vma = _vma_union(q3, k3, v3, kf, kt)
     if vma:
         q3, k3, v3, kf, kt = (_pvary_to(x, vma)
@@ -277,78 +321,19 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
             _sds((BH, 8, Tq), jnp.float32, vma),
         ],
         interpret=interpret,
+        compiler_params=_vmem_params(vmem_bytes(Tq, Tk, D, in_bytes)[0]),
         name="flash_fwd",
     )(kf, kt, q3, k3, v3)
 
 
 # -------------------------------------------------------------- backward
-def _dq_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, *, walk: Walk, n_kv: int,
-               sm_scale: float):
-    block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
-    qi = pl.program_id(1)
-    kfull = kf_ref[0, 0] != 0.0
-    ktri = kt_ref[0, 0] != 0.0
-    D = q_ref.shape[-1]
-
-    def rows_of(rows):
-        """q, dO, lse and delta of a row block."""
-        return (q_ref[0, rows, :].astype(jnp.bfloat16),
-                do_ref[0, rows, :].astype(jnp.bfloat16),
-                lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None])
-
-    def grad(qrows, lo, width, off=None):
-        """dq of a row block from keys [lo, lo + width), causally masked
-        when ``off`` is given."""
-        qb, dob, lse, delta = qrows
-        kb = k_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
-        vb = v_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
-        s = _dot(qb, kb, ((1,), (1,))) * sm_scale
-        if off is not None:
-            s = _causal(s, off)
-        # exp(NEG_BIG - lse) underflows to 0: masked entries need no
-        # second where (lse rows are finite wherever a row attends)
-        p = jnp.exp(s - lse)
-        ds = p * (_dot(dob, vb, ((1,), (1,))) - delta)
-        return _dot(ds.astype(jnp.bfloat16), kb, ((1,), (0,)))
-
-    whole = rows_of(slice(None))
-
-    def full_body(i, dq):
-        return dq + grad(whole, i * block_k, block_k)
-
-    def masked_body(i, dq):
-        return dq + grad(whole, i * block_k, block_k,
-                         qi * block_q - i * block_k)
-
-    n_full, hi = _q_walk(kfull, ktri, qi, walk, n_kv)
-    dq = lax.fori_loop(0, n_full, full_body,
-                       jnp.zeros((block_q, D), jnp.float32))
-    if not sub:
-        dq_ref[0] = lax.fori_loop(n_full, hi, masked_body, dq) * sm_scale
-        return
-    diag = ktri & ~kfull
-
-    @pl.when(~diag)
-    def _():
-        dq_ref[0] = dq * sm_scale
-
-    @pl.when(diag)
-    def _():
-        base = qi * block_q
-        for r in range(block_q // sub):
-            rows = slice(r * sub, (r + 1) * sub)
-            qrows = rows_of(rows)
-            dq_r = dq[rows]
-            if r:
-                dq_r = dq_r + grad(qrows, base, r * sub)
-            dq_r = dq_r + grad(qrows, base + r * sub, sub, 0)
-            dq_ref[0, rows, :] = dq_r * sm_scale
-
-
-def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, dk_ref, dv_ref, *, walk: Walk, n_q: int,
-                sm_scale: float):
+def _bwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dq_ref, dk_ref, dv_ref, *, walk: Walk, n_q: int,
+                n_kv: int, sm_scale: float):
+    """dq, dk and dv from one scoring of each (key tile, query tile)
+    pair. The grid walks key tiles innermost; dq is one block per (b, h)
+    that stays in VMEM across them, zeroed at the first and scaled at
+    the last."""
     block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
     ki = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
@@ -357,12 +342,17 @@ def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     vb = v_ref[0].astype(jnp.bfloat16)
     D = kb.shape[-1]
 
+    @pl.when(ki == 0)
+    def _():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
+
     def grad(kb, vb, lo, height, off=None, tail=0):
         """(dk, dv) of keys kb from queries [lo, lo + height), causally
-        masked from key row ``tail`` on when ``off`` is given. The scores
-        are taken transposed, keys by queries, so that no matmul needs a
-        transposed operand and lse and delta are read as the lane rows
-        they are stored in."""
+        masked from key row ``tail`` on when ``off`` is given; those
+        queries' dq from these keys is added into dq_ref. The scores
+        are taken transposed, keys by queries, so that only the dq
+        matmul takes a transposed operand and lse and delta are read as
+        the lane rows they are stored in."""
         qb = q_ref[0, pl.ds(lo, height), :].astype(jnp.bfloat16)
         dob = do_ref[0, pl.ds(lo, height), :].astype(jnp.bfloat16)
         lse = lse_ref[0, 0:1, pl.ds(lo, height)]      # [1, height]
@@ -376,7 +366,9 @@ def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         pt = jnp.exp(st - lse)  # masked entries underflow to exactly 0
         dv = _dot(pt.astype(jnp.bfloat16), dob, ((1,), (0,)))
         dst = pt * (_dot(vb, dob, ((1,), (1,))) - delta)
-        dk = _dot(dst.astype(jnp.bfloat16), qb, ((1,), (0,)))
+        dst = dst.astype(jnp.bfloat16)
+        dk = _dot(dst, qb, ((1,), (0,)))
+        dq_ref[0, pl.ds(lo, height), :] += _dot(dst, kb, ((0,), (0,)))
         return dk, dv
 
     def full_body(i, carry):
@@ -390,7 +382,7 @@ def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     # q tiles [lo, lo_full) touch the diagonal and run masked, then
     # [lo_full, n_q) lie wholly below it; q tiles above the diagonal
-    # contribute nothing to this kv tile's dk/dv
+    # attend to no key of this tile
     lo_tri = (ki * block_k) // block_q
     lo_full = ((ki + 1) * block_k + block_q - 2) // block_q
     lo = jnp.where(kfull, 0, jnp.where(ktri, lo_tri, n_q))
@@ -404,37 +396,42 @@ def _dkv_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                              (zeros, zeros)))
         dk_ref[0] = dk * sm_scale
         dv_ref[0] = dv
-        return
-    dk, dv = lax.fori_loop(lo_full, n_q, full_body, (zeros, zeros))
-    diag = ktri & ~kfull
+    else:
+        dk, dv = lax.fori_loop(lo_full, n_q, full_body, (zeros, zeros))
+        diag = ktri & ~kfull
 
-    @pl.when(~diag)
-    def _():
-        dk_ref[0] = dk * sm_scale
-        dv_ref[0] = dv
+        @pl.when(~diag)
+        def _():
+            dk_ref[0] = dk * sm_scale
+            dv_ref[0] = dv
 
-    @pl.when(diag)
+        @pl.when(diag)
+        def _():
+            # query sub-block r of the diagonal q tile reaches keys
+            # [0, (r+1)*sub) of this tile; only its own sub x sub square
+            # crosses the diagonal
+            n = block_k // sub
+            dks, dvs = [zeros[:sub]] * n, [zeros[:sub]] * n
+            for r in range(n):
+                a, b = grad(kb[:(r + 1) * sub], vb[:(r + 1) * sub],
+                            ki * block_q + r * sub, sub, r * sub, r * sub)
+                for c in range(r + 1):
+                    dks[c] = dks[c] + a[c * sub:(c + 1) * sub]
+                    dvs[c] = dvs[c] + b[c * sub:(c + 1) * sub]
+            dk_ref[0] = (dk + jnp.concatenate(dks)) * sm_scale
+            dv_ref[0] = dv + jnp.concatenate(dvs)
+
+    @pl.when(ki == n_kv - 1)
     def _():
-        # query sub-block r of the diagonal q tile reaches keys
-        # [0, (r+1)*sub) of this tile; only its own sub x sub square
-        # crosses the diagonal
-        n = block_k // sub
-        dks, dvs = [zeros[:sub]] * n, [zeros[:sub]] * n
-        for r in range(n):
-            a, b = grad(kb[:(r + 1) * sub], vb[:(r + 1) * sub],
-                        ki * block_q + r * sub, sub, r * sub, r * sub)
-            for c in range(r + 1):
-                dks[c] = dks[c] + a[c * sub:(c + 1) * sub]
-                dvs[c] = dvs[c] + b[c * sub:(c + 1) * sub]
-        dk_ref[0] = (dk + jnp.concatenate(dks)) * sm_scale
-        dv_ref[0] = dv + jnp.concatenate(dvs)
+        dq_ref[0] = dq_ref[0] * sm_scale
 
 
 def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
               walk: Walk, interpret: bool):
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
-    block_q, block_k = walk.block_q, walk.block_k
+    block_k = walk.block_k
+    in_bytes = max(x.dtype.itemsize for x in (q3, k3, v3))
     vma = _vma_union(q3, k3, v3, kf, kt, do3, lse, delta)
     if vma:
         q3, k3, v3, kf, kt, do3, lse, delta = (
@@ -444,47 +441,36 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, walk=walk, n_kv=Tk // block_k,
-                          sm_scale=sm_scale),
-        grid=(BH, Tq // block_q),
-        in_specs=flags + [
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, qi: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-        out_shape=_sds((BH, Tq, D), jnp.float32, vma),
-        interpret=interpret,
-        name="flash_dq",
-    )(kf, kt, q3, k3, v3, do3, lse, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, walk=walk, n_q=Tq // block_q,
-                          sm_scale=sm_scale),
+    whole = lambda bh, ki: (bh, 0, 0)
+    tile = lambda bh, ki: (bh, ki, 0)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, walk=walk, n_q=Tq // walk.block_q,
+                          n_kv=Tk // block_k, sm_scale=sm_scale),
         grid=(BH, Tk // block_k),
         in_specs=flags + [
-            pl.BlockSpec((1, Tq, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, Tq, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 8, Tq), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((1, 8, Tq), lambda bh, ki: (bh, 0, 0)),
+            pl.BlockSpec((1, Tq, D), whole),
+            pl.BlockSpec((1, block_k, D), tile),
+            pl.BlockSpec((1, block_k, D), tile),
+            pl.BlockSpec((1, Tq, D), whole),
+            pl.BlockSpec((1, 8, Tq), whole),
+            pl.BlockSpec((1, 8, Tq), whole),
         ],
+        # dq's block index is constant over the key tiles: it stays
+        # resident and is written back once per (b, h)
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, Tq, D), whole),
+            pl.BlockSpec((1, block_k, D), tile),
+            pl.BlockSpec((1, block_k, D), tile),
         ],
         out_shape=[
+            _sds((BH, Tq, D), jnp.float32, vma),
             _sds((BH, Tk, D), jnp.float32, vma),
             _sds((BH, Tk, D), jnp.float32, vma),
         ],
         interpret=interpret,
-        name="flash_dkv",
+        compiler_params=_vmem_params(vmem_bytes(Tq, Tk, D, in_bytes)[1]),
+        name="flash_dqkv",
     )(kf, kt, q3, k3, v3, do3, lse, delta)
-    return dq, dk, dv
 
 
 # ------------------------------------------------------------ public API
@@ -574,7 +560,9 @@ def flash_block(q, k, v, keep_full, keep_tri, sm_scale=None,
 
 
 def flash_supported(q_shape, k_shape, layout: str = "bthd") -> bool:
-    """Static gate: tiles must divide the shards and K/V must fit VMEM."""
+    """Static gate: tiles must divide the shards, lse rows must be sliced
+    in whole 128-lane tiles, and both kernels must fit the VMEM a kernel
+    may ask for (reckoned for f32 inputs, the widest they take)."""
     if layout == "bhtd":
         B, H, Tq, D = q_shape
         Tk = k_shape[2]
@@ -584,7 +572,6 @@ def flash_supported(q_shape, k_shape, layout: str = "bthd") -> bool:
     if Tq < 8 or Tk < 8 or D % 8:
         return False
     bq, bk = _pick_blocks(Tq, Tk, D)
-    if Tq % bq or Tk % bk or bq < 8 or bk < 8:
+    if Tq % bq or Tk % bk or bq % 128 or bk < 8:
         return False
-    # k+v tiles resident per (b,h) program: 2 * Tk * D * 4 bytes
-    return 2 * Tk * D * 4 <= 12 * (1 << 20)
+    return max(vmem_bytes(Tq, Tk, D, 4)) <= _VMEM_MAX

@@ -57,11 +57,11 @@ def _n_kernels(compiled) -> int:
 
 
 def _flash_kinds(compiled):
-    """The sorted kinds (fwd, dq, dkv) of the program's kernels, each
-    found both ways the benchmark finds it: by the name its pallas_call
-    gives the instruction (flash_fwd_ms, flash_bwd_ms) and by its
-    signature (flops.flash_kernel, for flash_attn_ms), read from HLO text
-    with operand shapes, as a device trace prints it."""
+    """The sorted kinds (fwd, dqkv) of the program's kernels, each found
+    as the benchmark finds it: every kernel by the name its pallas_call
+    gives the instruction (flash_fwd_ms, flash_bwd_ms), and the forward
+    also by its signature (flops.flash_kernel, for flash_attn_ms), read
+    from HLO text with operand shapes, as a device trace prints it."""
     from jax._src.lib import xla_client
 
     from benchmark import flops
@@ -72,10 +72,14 @@ def _flash_kinds(compiled):
     kinds = []
     for line in text.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
-            kind = flops.flash_kernel(line.strip())
-            assert kind is not None, line[:200]
-            assert "flash_" + kind[0] in line.split(" = ")[0]
-            kinds.append(kind[0])
+            name = line.split(" = ")[0]
+            if "flash_fwd" in name:
+                kind = flops.flash_kernel(line.strip())
+                assert kind is not None and kind[0] == "fwd", line[:200]
+                kinds.append("fwd")
+            else:
+                assert "flash_dqkv" in name, line[:200]
+                kinds.append("dqkv")
     return sorted(kinds)
 
 
@@ -101,9 +105,9 @@ def test_flash_backward_compiles(one_chip):
     grad = jax.grad(lambda q, k, v: jnp.sum(_attend(q, k, v)),
                     argnums=(0, 1, 2))
     compiled = jax.jit(grad).lower(*_qkv(one_chip)).compile()
-    # forward + the dq and dk/dv kernels of the backward
-    assert _n_kernels(compiled) == 3
-    assert _flash_kinds(compiled) == ["dkv", "dq", "fwd"]
+    # forward + the one backward kernel
+    assert _n_kernels(compiled) == 2
+    assert _flash_kinds(compiled) == ["dqkv", "fwd"]
 
 
 def _compile_step(topo, devices, batch):
@@ -140,9 +144,9 @@ def test_train_step_1x2x2_compiles(topo, no_compile_cache, flash_on):
     cotangents of their own type."""
     cfg, compiled = _compile_step(
         topo, np.array(topo.devices).reshape(1, 2, 2), 8)
-    # per layer, each of the 2 ring steps runs forward + dq + dk/dv
-    assert _n_kernels(compiled) == 2 * 3 * cfg.n_layers
-    assert _flash_kinds(compiled) == sorted(["fwd", "dq", "dkv"] * 2 *
+    # per layer, each of the 2 ring steps runs forward + backward
+    assert _n_kernels(compiled) == 2 * 2 * cfg.n_layers
+    assert _flash_kinds(compiled) == sorted(["fwd", "dqkv"] * 2 *
                                              cfg.n_layers)
 
 

@@ -2,11 +2,14 @@
 on CPU — the same kernel code the TPU path compiles; reference analog:
 the op/avx kernel unit tests, ompi/mca/op/avx)."""
 
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax._src import core
 
 from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.ops.flash_attention import (causal_walk, flash_block,
@@ -17,15 +20,22 @@ from ompi_tpu.ops.ring_attention import reference_attention
 # whole, and the flagship's per-head geometry, where 512-wide tiles
 # split their diagonal into sub-blocks
 GEOMS = {"t64_d16": (2, 64, 2, 16), "t1024_d128": (1, 1024, 2, 128)}
+# four 512-wide key tiles: dq gathers from more than two of them
+FOUR_TILES = {"t2048_d128": (1, 2048, 1, 128)}
 # (keep_full, keep_tri) of the ring's three block relations
 MODES = {"tri": (0.0, 1.0), "full": (1.0, 0.0), "none": (0.0, 0.0)}
 
 
+@functools.cache
+def _qkv(geom):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    shape = {**GEOMS, **FOUR_TILES}[geom]
+    return tuple(jax.random.normal(k, shape, jnp.float32) for k in ks)
+
+
 @pytest.fixture(scope="module", params=list(GEOMS))
 def qkv(request):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    return tuple(jax.random.normal(k, GEOMS[request.param], jnp.float32)
-                 for k in ks)
+    return _qkv(request.param)
 
 
 def _dense(q, k, v, mode):
@@ -66,11 +76,16 @@ def test_flash_bhtd_layout_matches(qkv):
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("mode", list(MODES))
-def test_flash_grads_match_dense(qkv, mode):
+GRAD_CASES = [*((g, m) for g in GEOMS for m in MODES),
+              *((g, "tri") for g in FOUR_TILES)]
+
+
+@pytest.mark.parametrize("geom,mode", GRAD_CASES,
+                         ids=["-".join(c) for c in GRAD_CASES])
+def test_flash_grads_match_dense(geom, mode):
     """dq/dk/dv (incl. the lse cotangent path the ring merge exercises)
     against autodiff through the dense reference."""
-    q, k, v = qkv
+    q, k, v = _qkv(geom)
 
     def floss(q_, k_, v_):
         o, l = flash_block(q_, k_, v_, *MODES[mode], interpret=True)
@@ -103,13 +118,54 @@ def test_ring_merge_with_flash_matches_dense():
     np.testing.assert_allclose(merged, ref, atol=2e-2, rtol=2e-2)
 
 
+def test_flash_backward_is_one_kernel():
+    """The backward scores each pair once: one pallas_call beside the
+    forward's, named as the benchmark's readers find it."""
+    q, k, v = _qkv("t64_d16")
+    grad = jax.grad(lambda *a: jnp.sum(flash_block(*a, 0.0, 1.0,
+                                                   interpret=True)[0]),
+                    argnums=(0, 1, 2))
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for sub in core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    jaxpr = jax.make_jaxpr(grad)(q, k, v).jaxpr
+    names = [e.params["name"] for e in eqns(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert names == ["flash_fwd", "flash_dqkv"]
+
+
 def test_flash_supported_gate():
     assert flash_supported((2, 1024, 4, 64), (2, 1024, 4, 64))
     assert not flash_supported((2, 7, 4, 64), (2, 7, 4, 64))  # odd seq
     assert flash_supported((2, 4, 1024, 64), (2, 4, 1024, 64),
                            layout="bhtd")
+    # lse rows are sliced in whole 128-lane tiles: 64-row tiles fall back
+    assert not flash_supported((2, 64, 2, 16), (2, 64, 2, 16))
     # K/V VMEM budget: enormous per-device KV must fall back
     assert not flash_supported((1, 256, 1, 128), (1, 1 << 20, 1, 128))
+    # the backward holds the whole q, dO and dq of a (b, h): at D = 128
+    # T = 24576 fits the VMEM a kernel may ask for, T = 32768 does not
+    assert flash_supported((1, 24576, 1, 128), (1, 24576, 1, 128))
+    assert not flash_supported((1, 32768, 1, 128), (1, 32768, 1, 128))
+
+
+def test_vmem_bytes_counts_the_resident_blocks():
+    """At the flagship's per-head shape both kernels fit the default
+    scoped limit, so neither asks for more; the backward's whole q, dO
+    and dq grow with T, the forward's whole k and v too."""
+    fwd, bwd = fa.vmem_bytes(1024, 1024, 128, 2)
+    assert max(fwd, bwd) <= fa._VMEM_DEFAULT
+    assert fa._vmem_params(bwd) is None
+    fwd8, bwd8 = fa.vmem_bytes(8192, 8192, 128, 2)
+    # q bf16 + dO and dq f32 of 7168 more rows, twice; lse and delta rows
+    assert bwd8 - bwd == 2 * 7168 * 128 * (2 + 2 * 4) + 2 * 2 * 8 * 7168 * 4
+    assert fwd8 - fwd == 2 * 2 * 7168 * 128 * 2
+    # a head dim under 128 lanes is padded to them
+    assert fa._block_bytes(1024, 64, 2) == fa._block_bytes(1024, 128, 2)
 
 
 @pytest.mark.parametrize("t,sub,visited", [
