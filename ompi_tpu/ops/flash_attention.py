@@ -25,6 +25,14 @@ Contract (shared with the lax fallback in ring_attention.py):
   residuals instead of O(T^2).
 - the lse cotangent is honored (it folds into the delta rows): the ring
   merge differentiates through exp(lse - lse_new), so g_lse != 0 mid-ring.
+
+``flash_attention`` is the one-shard causal call with grouped K/V heads
+(query head h reads K/V head h // group) and an optional sliding band
+(query i sees key j iff 0 <= i - j < window). K/V stay [B, Hkv, T, D]:
+the kernels read a K/V head's block for each of its query heads, and the
+backward sums each group's dK and dV. Such calls carry their own
+``pallas_call`` names (``flash_gqa_*``, ``flash_win_*``); the flagship's
+keep ``flash_fwd`` and ``flash_dqkv``.
 """
 
 from __future__ import annotations
@@ -108,6 +116,7 @@ class Walk(NamedTuple):
     sub: int      # diagonal sub-block width; 0 where the split is off
     visited: int  # score elements each of the two kernels computes
     masked: int   # of those, the elements that pass through the mask
+    window: int = 0  # the band's width; 0: plain causal
 
 
 def _tri_tiles(qi, block_q: int, block_k: int):
@@ -118,22 +127,39 @@ def _tri_tiles(qi, block_q: int, block_k: int):
     return full, touch
 
 
-def causal_walk(tq: int, tk: int, d: int) -> Walk:
+def _band_tiles(qi, block_q: int, block_k: int, window: int):
+    """(first KV tile the band of Q tile ``qi`` touches, first KV tile
+    wholly inside the band's lower edge), floored and not clamped."""
+    lo = (qi * block_q - window + 1) // block_k
+    lo_full = (qi * block_q + block_q - window + block_k - 1) // block_k
+    return lo, lo_full
+
+
+def causal_walk(tq: int, tk: int, d: int, window: int = 0) -> Walk:
     """The walk the kernels make for a Q shard of ``tq`` rows against a
     KV shard of ``tk`` with head dim ``d``: the blocks ``_pick_blocks``
     chooses, the diagonal sub-block (0 where the diagonal tile is not a
-    square of whole 128-lane tiles), and the elements a causal call
-    scores and masks per (b, h), the same in both kernels. A full
-    (keep_full) call scores tq*tk elements and masks none."""
+    square of whole 128-lane tiles, or a band narrower than a tile cuts
+    it), and the elements a causal call scores and masks per (b, h), the
+    same in both kernels. With a ``window`` the walk starts at the band's
+    lower edge, whose tiles are masked whole. A full (keep_full) call
+    scores tq*tk elements and masks none."""
     bq, bk = _pick_blocks(tq, tk, d)
     # lse rows are sliced on lanes: a sub-block spans whole 128-lane tiles
     square = bq == bk and tq == tk and bq % 128 == 0
-    sub = min(_DIAG_SUB, bq) if square else 0
+    split = square and (not window or window >= bq)
+    sub = min(_DIAG_SUB, bq) if split else 0
     n_kv = tk // bk
     visited = masked = 0
     for qi in range(tq // bq):
         full, touch = (min(x, n_kv) for x in _tri_tiles(qi, bq, bk))
-        visited += full * bq * bk
+        lo = lo_full = 0
+        if window:
+            lo, lo_full = _band_tiles(qi, bq, bk, window)
+            lo = min(max(lo, 0), full)
+            lo_full = min(max(lo_full, lo), full)
+        visited += (full - lo) * bq * bk
+        masked += (lo_full - lo) * bq * bk
         if sub:
             n = bq // sub
             visited += sub * sub * n * (n + 1) // 2
@@ -141,7 +167,7 @@ def causal_walk(tq: int, tk: int, d: int) -> Walk:
         else:
             visited += (touch - full) * bq * bk
             masked += (touch - full) * bq * bk
-    return Walk(bq, bk, sub, visited, masked)
+    return Walk(bq, bk, sub, visited, masked, window)
 
 
 # a kernel asks for VMEM beyond the compiler's default scoped limit
@@ -181,13 +207,17 @@ def _vmem_params(nbytes: int):
     return pltpu.CompilerParams(vmem_limit_bytes=nbytes)
 
 
-def _causal(s, off, key_axis: int = 1):
-    """Scores ``s`` with every key after its query set to NEG_BIG. Keys
+def _causal(s, off, key_axis: int = 1, window: int = 0):
+    """Scores ``s`` with every key after its query set to NEG_BIG, and
+    with a ``window`` every key ``window`` or more before it too. Keys
     run along ``key_axis`` and queries along the other; ``off`` is the
     first query's index less the first key's."""
     keys = lax.broadcasted_iota(jnp.int32, s.shape, key_axis)
     queries = lax.broadcasted_iota(jnp.int32, s.shape, 1 - key_axis) + off
-    return jnp.where(keys <= queries, s, NEG_BIG)
+    keep = keys <= queries
+    if window:
+        keep = keep & (queries - keys < window)
+    return jnp.where(keep, s, NEG_BIG)
 
 
 def _dot(a, b, contract):
@@ -216,6 +246,7 @@ def _q_walk(kfull, ktri, qi, walk: Walk, n_kv: int):
 def _fwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 walk: Walk, n_kv: int, sm_scale: float):
     block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
+    window = walk.window
     qi = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
     ktri = kt_ref[0, 0] != 0.0
@@ -228,7 +259,7 @@ def _fwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         kb = k_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
         vb = v_ref[0, pl.ds(lo, width), :].astype(jnp.bfloat16)
         s = _dot(qb, kb, ((1,), (1,))) * sm_scale
-        return (s if off is None else _causal(s, off)), vb
+        return (s if off is None else _causal(s, off, window=window)), vb
 
     def accumulate(parts, carry):
         """One online-softmax step over the (scores, values) parts of
@@ -267,7 +298,16 @@ def _fwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     m0 = jnp.full((block_q, 1), NEG_BIG, jnp.float32)
     den0 = jnp.zeros((block_q, 1), jnp.float32)
     n_full, hi = _q_walk(kfull, ktri, qi, walk, n_kv)
-    carry = lax.fori_loop(0, n_full, full_body, (acc0, m0, den0))
+    if window:
+        # the band's lower edge: tiles [lo, lo_full) masked, then
+        # [lo_full, n_full) unmasked (a band is causal: kfull is 0)
+        lo, lo_full = _band_tiles(qi, block_q, block_k, window)
+        lo = jnp.clip(lo, 0, n_full)
+        lo_full = jnp.clip(lo_full, lo, n_full)
+        carry = lax.fori_loop(lo, lo_full, masked_body, (acc0, m0, den0))
+        carry = lax.fori_loop(lo_full, n_full, full_body, carry)
+    else:
+        carry = lax.fori_loop(0, n_full, full_body, (acc0, m0, den0))
     if not sub:
         finish(slice(None), *lax.fori_loop(n_full, hi, masked_body, carry))
         return
@@ -287,8 +327,24 @@ def _fwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             finish(rows, *accumulate(parts, tuple(x[rows] for x in carry)))
 
 
+def _kv_map(group: int, tiled: bool):
+    """Index map of a K/V block: query head ``bh`` reads K/V head
+    ``bh // group``, its ``i``-th tile where ``tiled``, else whole."""
+    def index(bh, i):
+        b = bh if group == 1 else bh // group
+        return (b, i, 0) if tiled else (b, 0, 0)
+    return index
+
+
+def _name(kind: str, group: int, window: int) -> str:
+    """A kernel's pallas_call name: the flagship's plain causal calls
+    keep ``flash_<kind>``; grouped or banded calls name themselves."""
+    return "flash_" + ("gqa_" if group > 1 else "") + (
+        "win_" if window else "") + kind
+
+
 def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
-              interpret: bool):
+              interpret: bool, group: int = 1):
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
     block_q, block_k = walk.block_q, walk.block_k
@@ -304,13 +360,14 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
+    kv = _kv_map(group, False)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=flags + [
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, Tk, D), kv),
+            pl.BlockSpec((1, Tk, D), kv),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
@@ -322,7 +379,7 @@ def _fwd_call(q3, k3, v3, kf, kt, sm_scale: float, walk: Walk,
         ],
         interpret=interpret,
         compiler_params=_vmem_params(vmem_bytes(Tq, Tk, D, in_bytes)[0]),
-        name="flash_fwd",
+        name=_name("fwd", group, walk.window),
     )(kf, kt, q3, k3, v3)
 
 
@@ -335,6 +392,7 @@ def _bwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     that stays in VMEM across them, zeroed at the first and scaled at
     the last."""
     block_q, block_k, sub = walk.block_q, walk.block_k, walk.sub
+    window = walk.window
     ki = pl.program_id(1)
     kfull = kf_ref[0, 0] != 0.0
     ktri = kt_ref[0, 0] != 0.0
@@ -361,7 +419,7 @@ def _bwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if off is not None:
             # rows are whole vreg tiles on both sides of ``tail``: the
             # split and the concatenation move no data
-            sq = _causal(st[tail:], off - tail, key_axis=0)
+            sq = _causal(st[tail:], off - tail, key_axis=0, window=window)
             st = jnp.concatenate([st[:tail], sq]) if tail else sq
         pt = jnp.exp(st - lse)  # masked entries underflow to exactly 0
         dv = _dot(pt.astype(jnp.bfloat16), dob, ((1,), (0,)))
@@ -390,14 +448,22 @@ def _bwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         jnp.where(ktri, jnp.minimum(lo_full, n_q), n_q))
     lo, lo_full = lo.astype(jnp.int32), lo_full.astype(jnp.int32)
     zeros = jnp.zeros((block_k, D), jnp.float32)
+    hi_full, carry = n_q, (zeros, zeros)
+    if window:
+        # with a band, q tiles [lo_full, hi_full) see every key of this
+        # tile and [hi_full, hi) only some: the band's lower edge
+        hi_full = jnp.clip((ki * block_k + window) // block_q, lo_full, n_q)
+        hi = jnp.clip((ki * block_k + block_k + window + block_q - 2)
+                      // block_q, hi_full, n_q)
+        carry = lax.fori_loop(hi_full, hi, masked_body, carry)
     if not sub:
-        dk, dv = lax.fori_loop(lo_full, n_q, full_body,
+        dk, dv = lax.fori_loop(lo_full, hi_full, full_body,
                                lax.fori_loop(lo, lo_full, masked_body,
-                                             (zeros, zeros)))
+                                             carry))
         dk_ref[0] = dk * sm_scale
         dv_ref[0] = dv
     else:
-        dk, dv = lax.fori_loop(lo_full, n_q, full_body, (zeros, zeros))
+        dk, dv = lax.fori_loop(lo_full, hi_full, full_body, carry)
         diag = ktri & ~kfull
 
         @pl.when(~diag)
@@ -427,7 +493,9 @@ def _bwd_kernel(kf_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
-              walk: Walk, interpret: bool):
+              walk: Walk, interpret: bool, group: int = 1):
+    """dq [BH, Tq, D], and dk and dv per query head [BH, Tk, D] (a
+    group's sum is the caller's), all f32."""
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
     block_k = walk.block_k
@@ -443,14 +511,15 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
     ]
     whole = lambda bh, ki: (bh, 0, 0)
     tile = lambda bh, ki: (bh, ki, 0)
+    kv_tile = _kv_map(group, True)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, walk=walk, n_q=Tq // walk.block_q,
                           n_kv=Tk // block_k, sm_scale=sm_scale),
         grid=(BH, Tk // block_k),
         in_specs=flags + [
             pl.BlockSpec((1, Tq, D), whole),
-            pl.BlockSpec((1, block_k, D), tile),
-            pl.BlockSpec((1, block_k, D), tile),
+            pl.BlockSpec((1, block_k, D), kv_tile),
+            pl.BlockSpec((1, block_k, D), kv_tile),
             pl.BlockSpec((1, Tq, D), whole),
             pl.BlockSpec((1, 8, Tq), whole),
             pl.BlockSpec((1, 8, Tq), whole),
@@ -469,7 +538,7 @@ def _bwd_call(q3, k3, v3, kf, kt, do3, lse, delta, sm_scale: float,
         ],
         interpret=interpret,
         compiler_params=_vmem_params(vmem_bytes(Tq, Tk, D, in_bytes)[1]),
-        name="flash_dqkv",
+        name=_name("dqkv", group, walk.window),
     )(kf, kt, q3, k3, v3, do3, lse, delta)
 
 
@@ -491,24 +560,26 @@ def _from3(x, B, H, layout):
     return jnp.transpose(x.reshape(B, H, T, D), (0, 2, 1, 3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, kf, kt, sm_scale, interpret, layout):
-    out, _ = _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, kf, kt, sm_scale, interpret, layout, window):
+    out, _ = _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout,
+                        window)
     return out
 
 
-def _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout):
+def _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout, window):
     if layout == "bhtd":
         B, H, Tq, D = q.shape
-        Tk = k.shape[2]
+        Hkv, Tk = k.shape[1], k.shape[2]
     else:
         B, Tq, H, D = q.shape
-        Tk = k.shape[1]
-    walk = causal_walk(Tq, Tk, D)
+        Tk, Hkv = k.shape[1], k.shape[2]
+    walk = causal_walk(Tq, Tk, D, window)
     q3 = _to3(q, layout)
     k3 = _to3(k, layout)
     v3 = _to3(v, layout)
-    o3, lse8 = _fwd_call(q3, k3, v3, kf, kt, sm_scale, walk, interpret)
+    o3, lse8 = _fwd_call(q3, k3, v3, kf, kt, sm_scale, walk, interpret,
+                         H // Hkv)
     out = (_from3(o3, B, H, layout), lse8[:, 0, :].reshape(B, H, Tq))
     # the saved output rides in bf16: delta = rowsum(dO·O) tolerates the
     # rounding, and the f32 buffer would otherwise live across the whole
@@ -516,23 +587,29 @@ def _flash_fwd(q, k, v, kf, kt, sm_scale, interpret, layout):
     return out, (q3, k3, v3, kf, kt, o3.astype(jnp.bfloat16), lse8, B, H)
 
 
-def _flash_bwd(sm_scale, interpret, layout, res, g):
+def _flash_bwd(sm_scale, interpret, layout, window, res, g):
     q3, k3, v3, kf, kt, o3, lse8, B, H = res
     g_out, g_lse = g
     do3 = _to3(g_out, layout)
-    walk = causal_walk(q3.shape[1], k3.shape[1], q3.shape[2])
+    walk = causal_walk(q3.shape[1], k3.shape[1], q3.shape[2], window)
     # delta rows fold BOTH cotangent sources: rowsum(dO*O) from the output
     # and -g_lse from the ring merge's exp(lse - lse_new) factors
     delta = jnp.sum(do3 * o3, axis=-1) - g_lse.reshape(q3.shape[0], -1)
     delta8 = jnp.broadcast_to(delta[:, None, :], lse8.shape)
+    group = q3.shape[0] // k3.shape[0]
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, kf, kt, do3, lse8, delta8,
-                              sm_scale, walk, interpret)
+                              sm_scale, walk, interpret, group)
+    if group > 1:
+        # per query head -> per K/V head: the group's sum
+        dk3, dv3 = (x.reshape(k3.shape[0], group, *x.shape[1:]).sum(1)
+                    for x in (dk3, dv3))
     # the flags' zero cotangents keep the flags' own type: in the ring
     # they vary over the sp axis (axis_index), and a plain zeros((1, 1))
     # would not match it under shard_map
+    Hkv = H // group
     return (_from3(dq3, B, H, layout).astype(q3.dtype),
-            _from3(dk3, B, H, layout).astype(k3.dtype),
-            _from3(dv3, B, H, layout).astype(v3.dtype),
+            _from3(dk3, B, Hkv, layout).astype(k3.dtype),
+            _from3(dv3, B, Hkv, layout).astype(v3.dtype),
             jnp.zeros_like(kf), jnp.zeros_like(kt))
 
 
@@ -556,20 +633,42 @@ def flash_block(q, k, v, keep_full, keep_tri, sm_scale=None,
     kf = jnp.asarray(keep_full, jnp.float32).reshape(1, 1)
     kt = jnp.asarray(keep_tri, jnp.float32).reshape(1, 1)
     return _flash(q, k, v, kf, kt, float(sm_scale), bool(interpret),
-                  str(layout))
+                  str(layout), 0)
+
+
+def flash_attention(q, k, v, window: int = 0, sm_scale=None,
+                    interpret: bool = False):
+    """Causal attention of one whole sequence, layout 'bhtd': q [B, H,
+    T, D], k/v [B, Hkv, T, D] with H a multiple of Hkv, query head h
+    reading K/V head h // (H / Hkv). With ``window`` > 0 query i sees key
+    j iff 0 <= i - j < window. Returns out [B, H, T, D] f32, normalized.
+    bf16 on the MXU, f32 accumulation."""
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads do not group over "
+                         f"{k.shape[1]} K/V heads")
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    kf = jnp.zeros((1, 1), jnp.float32)
+    kt = jnp.ones((1, 1), jnp.float32)
+    out, _ = _flash(q, k, v, kf, kt, float(sm_scale), bool(interpret),
+                    "bhtd", int(window))
+    return out
 
 
 def flash_supported(q_shape, k_shape, layout: str = "bthd") -> bool:
     """Static gate: tiles must divide the shards, lse rows must be sliced
-    in whole 128-lane tiles, and both kernels must fit the VMEM a kernel
-    may ask for (reckoned for f32 inputs, the widest they take)."""
+    in whole 128-lane tiles, query heads must group over the K/V heads,
+    and both kernels must fit the VMEM a kernel may ask for (reckoned for
+    f32 inputs, the widest they take). A grouped or banded call holds the
+    same blocks per (b, h) program as a plain one: its K/V block is the
+    group's, and dK, dV are per query head until the caller sums them."""
     if layout == "bhtd":
         B, H, Tq, D = q_shape
-        Tk = k_shape[2]
+        Hkv, Tk = k_shape[1], k_shape[2]
     else:
         B, Tq, H, D = q_shape
-        Tk = k_shape[1]
-    if Tq < 8 or Tk < 8 or D % 8:
+        Tk, Hkv = k_shape[1], k_shape[2]
+    if Tq < 8 or Tk < 8 or D % 8 or H % Hkv:
         return False
     bq, bk = _pick_blocks(Tq, Tk, D)
     if Tq % bq or Tk % bk or bq % 128 or bk < 8:

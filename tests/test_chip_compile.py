@@ -110,6 +110,48 @@ def test_flash_backward_compiles(one_chip):
     assert _flash_kinds(compiled) == ["dqkv", "fwd"]
 
 
+def test_gqa_flash_compiles(one_chip):
+    """Mellum2's sliding layer at its cell's shape: 32 query heads over 4
+    K/V heads, T = 8192, a band of 1024; forward and backward, each under
+    its own name."""
+    from ompi_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v, 1024)),
+                    argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_gqa_win_fwd" in text and "flash_gqa_win_dqkv" in text
+
+
+def test_expert_layer_compiles(one_chip):
+    """The expert layer at the cell's shape: 8,192 tokens of 2,304, a
+    64-wide router, top 8, 8 experts held of width 896; the grouped
+    matmuls' forward (as the backward recomputes it: the gradient alone
+    needs no first forward), dx and dw, gate-up and down each."""
+    from ompi_tpu.ops.moe import moe_layer
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def loss(h, r, g, u, d):
+        out, rows = moe_layer(h, r, g, u, d, 8, impl="kernel")
+        return jnp.sum(out)
+
+    args = (shape(8192, 2304), shape(2304, 64), shape(8, 2304, 896),
+            shape(8, 2304, 896), shape(8, 896, 2304))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    names = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(names) == sorted(["expert_gmm", "expert_gmm_dx",
+                                    "expert_gmm_dw"] * 2), names
+
+
 def _compile_step(topo, devices, batch):
     """The flagship step at full width, two layers, compiled for a
     (dp, sp, tp) mesh of the described chips."""
