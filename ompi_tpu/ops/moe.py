@@ -11,22 +11,28 @@ chip's. On one chip the layer runs without its exchange.
   renormalised over the k (``norm_topk_prob``).
 - ``route``: sorts the (token, slot) assignments held here by expert,
   into a buffer where each expert's rows start on a ``TILE``-row tile.
-  Dropless: the buffer holds N·min(k, n_held) rows and a tile's padding
-  per expert, the worst case, so no token is ever dropped. Every expert
-  gets at least one tile, so the weight gradient writes all of them.
+  Every expert gets at least one tile, so the weight gradient writes
+  all of them.
+- Two heights, both dropless. ``compact_rows``: twice the even share of
+  the held experts' assignments (2·N·k·n_held / E) and a tile per
+  expert. ``buffer_rows``: the worst case, N·min(k, n_held) rows and a
+  tile per expert. ``moe_layer`` takes the compact buffer whenever the
+  routed tiles fit it, the worst case otherwise: a ``lax.switch`` on
+  the device, so no token is ever dropped and the host never waits.
 - ``expert_matmul``: a grouped matmul over the buffer (``expert_gmm``);
   its grid walks only the tiles that hold rows, so padding past them
   costs nothing. Its backward is ``expert_gmm_dx`` (the transposed
   weights) and ``expert_gmm_dw`` (the weights' gradient, per expert).
-- ``dispatch`` and ``combine``: gathers both ways, forward and backward
-  (no scatter-add): token rows into the buffer, and the buffer's rows,
-  weighted, back to their tokens.
+- ``dispatch`` and ``combine`` work on the buffer's rows: the dispatch
+  gathers each row's token, the combine adds each valid row, by its
+  slot's weight, into its token (a scatter-add), and each backward is
+  the other's form.
 
 ``moe_layer`` runs them under the named scopes ``moe.router``,
-``moe.dispatch``, ``moe.experts`` and ``moe.combine``, and recomputes
-the buffer in the backward (``jax.checkpoint``): only the layer's input,
-the weights and the integer routing are kept, never the N·k-row buffer.
-It returns the rows each held expert received.
+``moe.dispatch``, ``moe.experts`` and ``moe.combine``, and computes the
+taken branch's forward again in its backward, as ``jax.checkpoint``
+would: only the layer's input, the weights and the integer routing are
+kept, never the buffer. It returns the rows each held expert received.
 """
 
 from __future__ import annotations
@@ -50,7 +56,6 @@ _VMEM_DEFAULT = 16 << 20
 class Routing(NamedTuple):
     """Where each held (token, slot) assignment sits in the buffer."""
     held: jax.Array         # [N, k] bool: the slot's expert is held here
-    slot_row: jax.Array     # [N*k] int32: buffer row of each slot (0 if not held)
     row_token: jax.Array    # [M] int32: token of each buffer row (0: padding)
     row_slot: jax.Array     # [M] int32: flat slot of each row (0: padding)
     row_valid: jax.Array    # [M] bool: the row holds an assignment
@@ -60,9 +65,18 @@ class Routing(NamedTuple):
 
 
 def buffer_rows(n_tokens: int, top_k: int, n_held: int) -> int:
-    """The buffer's static height: every token's assignments held here
-    (at most min(k, n_held) each) and a tile of padding per expert."""
+    """The worst case's height: every token's assignments held here (at
+    most min(k, n_held) each) and a tile of padding per expert."""
     return n_tokens * min(top_k, n_held) + n_held * TILE
+
+
+def compact_rows(n_tokens: int, top_k: int, n_held: int,
+                 n_experts: int) -> int:
+    """The compact buffer's height: twice the held experts' even share
+    of the N·k assignments over all ``n_experts``, on whole tiles, and
+    a tile per expert."""
+    share = -(-2 * n_tokens * top_k * n_held // n_experts)
+    return -(-share // TILE) * TILE + n_held * TILE
 
 
 def router(h, w_router, top_k: int):
@@ -74,19 +88,37 @@ def router(h, w_router, top_k: int):
     return w / jnp.sum(w, axis=-1, keepdims=True), experts
 
 
-def route(experts, first: int, n_held: int) -> Routing:
-    """The buffer layout of the assignments to experts ``first ..
-    first + n_held - 1``: grouped by expert in token order, each expert's
-    rows padded to whole tiles (at least one)."""
-    n, k = experts.shape
-    m = buffer_rows(n, k, n_held)
+def _held_onehot(experts, first: int, n_held: int):
+    """(local expert of each flat slot, held [N*k] bool, one-hot [N*k,
+    n_held] int32)."""
     local = experts.reshape(-1) - first
     held = (local >= 0) & (local < n_held)
     onehot = (local[:, None] == jnp.arange(n_held)[None]).astype(jnp.int32)
+    return local, held, onehot
+
+
+def _tiles(counts):
+    """The tiles each held expert's rows fill: at least one."""
+    return (jnp.maximum(counts, 1) + TILE - 1) // TILE
+
+
+def tile_rows(counts):
+    """The buffer rows the routing fills: each expert's rows on whole
+    tiles."""
+    return jnp.sum(_tiles(counts)) * TILE
+
+
+def route(experts, first: int, n_held: int, m: int) -> Routing:
+    """The layout of the assignments to experts ``first .. first +
+    n_held - 1`` in a buffer of ``m`` rows: grouped by expert in token
+    order, each expert's rows padded to whole tiles (at least one).
+    Dropless where ``tile_rows`` fits ``m``."""
+    n, k = experts.shape
+    local, held, onehot = _held_onehot(experts, first, n_held)
     counts = jnp.sum(onehot, axis=0)
     # rank of each assignment among its expert's, in token order
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
-    tiles = (jnp.maximum(counts, 1) + TILE - 1) // TILE
+    tiles = _tiles(counts)
     ends = jnp.cumsum(tiles)
     start = (ends - tiles) * TILE
     row = jnp.where(held, start[jnp.clip(local, 0, n_held - 1)] + rank, m)
@@ -96,10 +128,9 @@ def route(experts, first: int, n_held: int) -> Routing:
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(m // TILE), side="right"),
         n_held - 1).astype(jnp.int32)
-    return Routing(held=held.reshape(n, k),
-                   slot_row=jnp.where(held, row, 0).astype(jnp.int32),
-                   row_token=row_slot // k, row_slot=row_slot,
-                   row_valid=row_valid, tile_expert=tile_expert,
+    return Routing(held=held.reshape(n, k), row_token=row_slot // k,
+                   row_slot=row_slot, row_valid=row_valid,
+                   tile_expert=tile_expert,
                    n_tiles=ends[-1].astype(jnp.int32), counts=counts)
 
 
@@ -251,10 +282,24 @@ _kernel_matmul.defvjp(_em_fwd, _em_bwd)
 
 
 # ------------------------------------------------ dispatch and combine
+def _row_dest(r: Routing, n: int):
+    """Each buffer row's token, and ``n`` (out of range: a scatter with
+    ``mode="drop"`` skips it) for a padding row."""
+    return jnp.where(r.row_valid, r.row_token, n)
+
+
+def _scatter_rows(rows, r: Routing):
+    """[N, D] f32: each valid buffer row of ``rows`` [M, D] added into
+    its token; padding rows, whatever they hold, are skipped."""
+    n = r.held.shape[0]
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[
+        _row_dest(r, n)].add(rows.astype(jnp.float32), mode="drop")
+
+
 @jax.custom_vjp
 def dispatch(h, r: Routing):
     """The buffer [M, D]: each row the token it holds (padding: token
-    0). Backward: each token sums its held slots' rows."""
+    0). Backward: each token sums its rows."""
     return h[r.row_token]
 
 
@@ -264,10 +309,7 @@ def _dispatch_fwd(h, r):
 
 def _dispatch_bwd(res, g):
     r, h0 = res
-    n, k = r.held.shape
-    rows = g[r.slot_row].reshape(n, k, -1).astype(jnp.float32)
-    dh = jnp.sum(jnp.where(r.held[..., None], rows, 0.0), axis=1)
-    return dh.astype(h0.dtype), None
+    return _scatter_rows(g, r).astype(h0.dtype), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -275,11 +317,9 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 @jax.custom_vjp
 def combine(ys, weights, r: Routing):
-    """out [N, D] f32: each token's held slots' rows, by their weights."""
-    n, k = r.held.shape
-    y = ys[r.slot_row].reshape(n, k, -1).astype(jnp.float32)
-    return jnp.sum(jnp.where(r.held[..., None], weights[..., None] * y, 0.0),
-                   axis=1)
+    """out [N, D] f32: each token's rows, by their slots' weights."""
+    w_row = weights.reshape(-1)[r.row_slot]
+    return _scatter_rows(w_row[:, None] * ys.astype(jnp.float32), r)
 
 
 def _combine_fwd(ys, weights, r):
@@ -289,12 +329,16 @@ def _combine_fwd(ys, weights, r):
 def _combine_bwd(res, g):
     ys, weights, r = res
     n, k = r.held.shape
+    g_row = g[r.row_token]
     w_row = weights.reshape(-1)[r.row_slot]
-    dys = jnp.where(r.row_valid[:, None], w_row[:, None] * g[r.row_token],
+    dys = jnp.where(r.row_valid[:, None], w_row[:, None] * g_row,
                     0.0).astype(ys.dtype)
-    y = ys[r.slot_row].reshape(n, k, -1).astype(jnp.float32)
-    dw = jnp.where(r.held, jnp.einsum("nkd,nd->nk", y, g), 0.0)
-    return dys, dw.astype(weights.dtype), None
+    # each held slot has one row: its weight's gradient is that row's
+    # output against the token's cotangent
+    dw_row = jnp.sum(ys.astype(jnp.float32) * g_row, axis=1)
+    dw = jnp.zeros((n * k,), jnp.float32).at[
+        jnp.where(r.row_valid, r.row_slot, n * k)].set(dw_row, mode="drop")
+    return dys, dw.reshape(n, k).astype(weights.dtype), None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -315,19 +359,77 @@ def _experts(h, weights, r: Routing, w_gate, w_up, w_down, impl: str):
         return combine(ys, weights, r)
 
 
+def _at(rows: int, first: int, impl: str):
+    """The layer's held part in a buffer of ``rows``: routed, then the
+    experts."""
+    def layer(h, weights, experts, w_gate, w_up, w_down):
+        with jax.named_scope("moe.dispatch"):
+            r = route(experts, first, w_gate.shape[0], rows)
+        return _experts(h, weights, r, w_gate, w_up, w_down, impl)
+    return layer
+
+
+def _vjp_at(rows: int, first: int, impl: str):
+    """The held part's forward again and its backward, in a buffer of
+    ``rows``: the cotangents of h, the weights and the experts'."""
+    def layer_vjp(h, weights, experts, w_gate, w_up, w_down, g):
+        def f(h, weights, w_gate, w_up, w_down):
+            return _at(rows, first, impl)(h, weights, experts, w_gate, w_up,
+                                          w_down)
+        return jax.vjp(f, h, weights, w_gate, w_up, w_down)[1](g)
+    return layer_vjp
+
+
+def _branch(counts, heights):
+    """0 where the routed tiles fit the first height, else the last."""
+    return (tile_rows(counts) > heights[0]).astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_part(heights, first, impl, counts, h, weights, experts, w_gate,
+               w_up, w_down):
+    """The held part at the first of ``heights`` that the routing fits.
+    Its backward computes the forward again (as ``jax.checkpoint``
+    would): only the inputs are kept, never the buffer."""
+    return lax.switch(_branch(counts, heights),
+                      [_at(m, first, impl) for m in heights],
+                      h, weights, experts, w_gate, w_up, w_down)
+
+
+def _held_fwd(heights, first, impl, counts, *args):
+    return _held_part(heights, first, impl, counts, *args), (counts, args)
+
+
+def _held_bwd(heights, first, impl, res, g):
+    counts, args = res
+    dh, dw, dg, du, dd = lax.switch(
+        _branch(counts, heights), [_vjp_at(m, first, impl) for m in heights],
+        *args, g)
+    return None, dh, dw, None, dg, du, dd
+
+
+_held_part.defvjp(_held_fwd, _held_bwd)
+
+
 def moe_layer(h, w_router, w_gate, w_up, w_down, top_k: int,
               first: int = 0, impl=None):
     """The held experts' part of the layer for tokens h [N, D]: (out
     [N, D] f32, rows each held expert received [n_held] int32). w_router
     [D, E_all]; w_gate, w_up [n_held, D, F]; w_down [n_held, F, D]. The
+    buffer is the compact one where the routed tiles fit it, else the
+    worst case (one height where the compact is not the smaller). The
     grouped matmul is ``impl``'s (``expert_matmul``): by default the
     kernel on a TPU, XLA elsewhere."""
     if impl is None:
         impl = "kernel" if jax.default_backend() == "tpu" else "xla"
+    n, n_held = h.shape[0], w_gate.shape[0]
     with jax.named_scope("moe.router"):
         weights, experts = router(h, w_router, top_k)
     with jax.named_scope("moe.dispatch"):
-        r = route(experts, first, w_gate.shape[0])
-    out = jax.checkpoint(functools.partial(_experts, impl=impl))(
-        h, weights, r, w_gate, w_up, w_down)
-    return out, r.counts
+        counts = jnp.sum(_held_onehot(experts, first, n_held)[2], axis=0)
+    worst = buffer_rows(n, top_k, n_held)
+    compact = compact_rows(n, top_k, n_held, w_router.shape[1])
+    heights = (compact, worst) if compact < worst else (worst,)
+    out = _held_part(heights, first, impl, counts, h, weights, experts,
+                     w_gate, w_up, w_down)
+    return out, counts

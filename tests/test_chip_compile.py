@@ -129,9 +129,11 @@ def test_gqa_flash_compiles(one_chip):
 
 def test_expert_layer_compiles(one_chip):
     """The expert layer at the cell's shape: 8,192 tokens of 2,304, a
-    64-wide router, top 8, 8 experts held of width 896; the grouped
-    matmuls' forward (as the backward recomputes it: the gradient alone
-    needs no first forward), dx and dw, gate-up and down each."""
+    64-wide router, top 8, 8 experts held of width 896. Two branches,
+    each with the grouped matmuls' forward (as the backward recomputes
+    it: the gradient alone needs no first forward), dx and dw, gate-up
+    and down each: the compact buffer's 17,408 rows and the worst
+    case's 66,560."""
     from ompi_tpu.ops.moe import moe_layer
 
     def shape(*s):
@@ -145,11 +147,17 @@ def test_expert_layer_compiles(one_chip):
             shape(8, 2304, 896), shape(8, 896, 2304))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile().as_text()
-    names = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
-             for ln in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
-    assert sorted(names) == sorted(["expert_gmm", "expert_gmm_dx",
-                                    "expert_gmm_dw"] * 2), names
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    for rows in (17408, 66560):
+        # a kernel's buffer operand: its first [rows, ...] array
+        mine = [ln for ln in kernels if f"[{rows}," in ln]
+        names = [ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                 for ln in mine]
+        assert sorted(names) == sorted(["expert_gmm", "expert_gmm_dx",
+                                        "expert_gmm_dw"] * 2), (rows, names)
+    assert len(kernels) == 12
+    assert "conditional(" in text and "[17408,2304]" in text
 
 
 def _compile_step(topo, devices, batch):

@@ -4,6 +4,8 @@ interpreted on the CPU and on XLA's path; the routing under skew; and
 the expert-parallel share: the parts all the shares compute add up to
 the uncut layer."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ from benchmark.reference import mellum as ref
 from ompi_tpu.ops import moe
 
 D, F, E_ALL, K = 64, 128, 16, 4
+E_WIDE = 32  # a router this wide makes the compact buffer the smaller
 
 
 def _shape(held, first=0):
@@ -24,14 +27,16 @@ def _shape(held, first=0):
                      d_expert=F, seq_len=8, lr=0.0)
 
 
-def _layer(n, skew, seed=0):
+def _layer(n, skew, seed=0, n_experts=E_ALL, favoured=1):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     h = jax.random.normal(ks[0], (n, D), jnp.float32)
-    router = jax.random.normal(ks[1], (D, E_ALL), jnp.float32) / np.sqrt(D)
+    router = jax.random.normal(ks[1], (D, n_experts),
+                               jnp.float32) / np.sqrt(D)
     if skew:
-        # every token puts expert 0 first: its rows fill several tiles
+        # every token puts experts 0 .. favoured - 1 first: their rows
+        # fill several tiles
         h = h + 2.0
-        router = router.at[:, 0].add(0.5)
+        router = router.at[:, :favoured].add(0.5)
     blk = {"router": router,
            "w_gate": jax.random.normal(ks[2], (E_ALL, D, F)) / np.sqrt(D),
            "w_up": jax.random.normal(ks[3], (E_ALL, D, F)) / np.sqrt(D),
@@ -59,6 +64,15 @@ def _close(a, b, tol=2e-2):
     np.testing.assert_allclose(a, b, atol=tol * scale, rtol=0)
 
 
+def _eqns(jaxpr):
+    from jax._src import core
+
+    for e in jaxpr.eqns:
+        yield e
+        for sub in core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
 def test_route_skewed_drops_nothing():
     """All 256 tokens pick expert 0: it gets 256 rows over two tiles,
     every held assignment a row of its own within its expert's tiles,
@@ -66,16 +80,17 @@ def test_route_skewed_drops_nothing():
     h, blk = _layer(256, skew=True)
     _, experts = moe.router(h, blk["router"], K)
     assert bool(jnp.all(experts[:, 0] == 0))
-    r = moe.route(experts, 0, 8)
+    r = moe.route(experts, 0, 8, moe.buffer_rows(256, K, 8))
     held = np.asarray(r.held).reshape(-1)
-    rows = np.asarray(r.slot_row)[held]
-    assert len(set(rows.tolist())) == held.sum() == int(r.counts.sum())
+    rows = np.flatnonzero(np.asarray(r.row_valid))
+    slots = np.asarray(r.row_slot)[rows]
+    # every held slot has exactly one row
+    np.testing.assert_array_equal(np.sort(slots), np.flatnonzero(held))
+    assert len(rows) == held.sum() == int(r.counts.sum())
     assert int(r.counts[0]) == 256
-    tok = np.repeat(np.arange(256), K)[held]
-    np.testing.assert_array_equal(np.asarray(r.row_token)[rows], tok)
-    assert int(np.asarray(r.row_valid).sum()) == held.sum()
+    np.testing.assert_array_equal(np.asarray(r.row_token)[rows], slots // K)
     te = np.asarray(r.tile_expert)
-    e = np.asarray(experts).reshape(-1)[held]
+    e = np.asarray(experts).reshape(-1)[slots]
     np.testing.assert_array_equal(te[rows // moe.TILE], e)
     tiles = np.maximum(-(-np.asarray(r.counts) // moe.TILE), 1)
     assert int(r.n_tiles) == tiles.sum()
@@ -136,18 +151,75 @@ def test_router_renormalises_over_the_top_k():
 
 def test_grouped_matmul_kernel_names():
     """The three calls are named as the benchmark's readers find them."""
-    from jax._src import core
-
     h, blk = _layer(128, skew=False)
     mine = _share(blk, 0, 4)
     f = jax.grad(lambda b: jnp.sum(_prog(h, b, 0, "interpret")[0]))
-
-    def eqns(jaxpr):
-        for e in jaxpr.eqns:
-            yield e
-            for sub in core.jaxprs_in_params(e.params):
-                yield from eqns(sub)
-
-    names = {e.params["name"] for e in eqns(jax.make_jaxpr(f)(mine).jaxpr)
+    names = {e.params["name"] for e in _eqns(jax.make_jaxpr(f)(mine).jaxpr)
              if e.primitive.name == "pallas_call"}
     assert names == {"expert_gmm", "expert_gmm_dx", "expert_gmm_dw"}
+
+
+@pytest.mark.parametrize("routing,held,n_experts,rows", [
+    # 4 of 32 held, 256 tokens: compact 768 rows, worst case 1,536
+    ("spread", 4, E_WIDE, 768),      # ~128 rows, 4 tiles: the compact
+    ("skewed", 4, E_WIDE, 1536),     # all pick 0-3: 8 tiles, the worst
+    ("all_held", E_ALL, E_ALL, 3072),  # compact 4,096 no smaller: one
+])
+def test_moe_layer_buffer_follows_the_routing(routing, held, n_experts,
+                                              rows, monkeypatch):
+    """The layer runs at the compact height where the routed tiles fit
+    it and at the worst case where they do not, with one height where
+    the compact is not the smaller; dropless either way: every held
+    assignment has a row, and the output and the gradients of h, the
+    router and the experts' weights match the reference's."""
+    h, blk = _layer(256, routing == "skewed", n_experts=n_experts,
+                    favoured=4)
+    mine = _share(blk, 0, held)
+    worst = moe.buffer_rows(256, K, held)
+    compact = moe.compact_rows(256, K, held, n_experts)
+    heights = []
+    matmul = moe.expert_matmul
+
+    def spy(x, *a):
+        # runs on the device, so only in the branch the layer takes
+        jax.debug.callback(functools.partial(heights.append, x.shape[0]))
+        return matmul(x, *a)
+
+    monkeypatch.setattr(moe, "expert_matmul", spy)
+
+    def prog(h, b):
+        return _prog(h, b, 0, "interpret")[0]
+
+    out, counts = _prog(h, mine, 0, "interpret")
+    w = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+    gp = jax.grad(lambda *a: jnp.sum(prog(*a) * w), argnums=(0, 1))(h, mine)
+    jax.effects_barrier()
+    assert heights and set(heights) == {rows}
+    branches = any(e.primitive.name in ("cond", "switch") for e in
+                   _eqns(jax.make_jaxpr(prog)(h, mine).jaxpr))
+    assert branches == (compact < worst)
+    fits = int(moe.tile_rows(counts)) <= compact
+    assert rows == (compact if fits and compact < worst else worst)
+
+    _, experts = moe.router(h, blk["router"], K)
+    r = moe.route(experts, 0, held, rows)
+    n_held = int(np.asarray(r.held).sum())
+    assert int(np.asarray(r.row_valid).sum()) == n_held == int(counts.sum())
+    assert int(r.n_tiles) * moe.TILE <= rows
+
+    _close(out, _ref(h, mine, held))
+    gr = jax.grad(lambda *a: jnp.sum(_ref(*a, held) * w),
+                  argnums=(0, 1))(h, mine)
+    for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
+        _close(a, b, 3e-2)
+
+
+@pytest.mark.parametrize("n,k,held,n_experts,compact,worst", [
+    # Mellum2's cell: 8,192 tokens, top 8 of 64, this chip's 8 held
+    (8192, 8, 8, 64, 17408, 66560),
+    # twice the even share (171.4 rows) rounds up to whole tiles
+    (100, 3, 2, 7, 512, 456),
+])
+def test_buffer_heights(n, k, held, n_experts, compact, worst):
+    assert moe.compact_rows(n, k, held, n_experts) == compact
+    assert moe.buffer_rows(n, k, held) == worst
